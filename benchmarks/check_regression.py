@@ -24,8 +24,8 @@ Two severity tiers:
   bench). --runs N interleaves N fresh subset runs for more samples.
   The distributional gate is what lets --max-regress sit at 1.3x on
   compute-bound rows where the old point-ratio gate needed a 2.5x noise
-  allowance. BENCH_kernels.json + BENCH_distributed.json form a real
-  measured trajectory, so the hot rows gate merges instead of informing.
+  allowance. BENCH_kernels.json holds CPU timings: the gate guards the
+  CPU lane against regressions and says nothing about the chip.
 
 Usage:
   python -m benchmarks.check_regression                   # runs subset itself

@@ -1,28 +1,13 @@
 """Distributed scale-out bench: ShardedHasher / DeviceShardedBloom vs the
-single-device engine, emitting BENCH_distributed.json.
+single-device engine, as the `distributed` module of `benchmarks.run`.
 
-Two entry points:
-
-- `run()` (the `distributed` module of `benchmarks.run`): benches on the
-  LIVE device set -- on the 1-device CI runner this measures the shard_map
-  degrade overhead (mesh of size 1, same code path), which must stay small.
-- `python -m benchmarks.distributed_bench --devices D` (standalone): re-execs
-  itself in a subprocess with D fake host CPU devices
-  (`--xla_force_host_platform_device_count`, the dry-run contract: only a
-  subprocess pins a device count) and writes BENCH_distributed.json with
-  single-device vs D-device rows.
-
-CPU fake devices share the physical cores, so D-device CPU rows measure the
-COLLECTIVE LAYOUT cost (shard_map partitioning, psum round-trips), not real
-scaling; on a TPU mesh the same rows become the actual throughput claim.
+`run()` benches on the LIVE device set in the calling process -- on a
+one-device CPU runner this measures the shard_map degrade overhead (mesh of
+size 1, same code path), which must stay small. There is no fake-device mode:
+a CPU process with forced host devices measures CPU collectives, and its
+rows would pass for device numbers.
 """
 from __future__ import annotations
-
-import argparse
-import json
-import os
-import subprocess
-import sys
 
 import numpy as np
 
@@ -209,53 +194,3 @@ def run() -> None:
     _bench_meshes([("single", None), (f"D{d}", mesh)])
     _bench_tree([("single", None), (f"D{d}", mesh)])
     _bench_service()
-
-
-def _child(json_path: str) -> None:
-    """Subprocess body: D fake devices are live; bench D=1 vs D=full."""
-    from repro.parallel.sharding import data_mesh
-
-    full = data_mesh()
-    d = full.devices.size
-    _bench_meshes([("single", None), ("D1", data_mesh(max_devices=1)),
-                   (f"D{d}", full)])
-    _bench_tree([("single", None), ("D1", data_mesh(max_devices=1)),
-                 (f"D{d}", full)])
-    _bench_service()
-    payload = {"schema": "bench-v1", "ref_hz": common.REF_HZ,
-               "fast": common.FAST, "devices": d, "rows": common.JSON_ROWS}
-    with open(json_path, "w") as f:
-        json.dump(payload, f, indent=1)
-    print(f"# wrote {len(common.JSON_ROWS)} rows -> {json_path}")
-
-
-def main(argv=None) -> None:
-    ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--devices", type=int, default=4,
-                    help="fake host device count for the subprocess mesh")
-    ap.add_argument("--fast", action="store_true",
-                    help="small sizes / few repeats (CI smoke)")
-    ap.add_argument("--json", default="BENCH_distributed.json")
-    ap.add_argument("--_child", action="store_true", help=argparse.SUPPRESS)
-    args = ap.parse_args(argv)
-    common.FAST = bool(args.fast)
-
-    if args._child:
-        _child(args.json)
-        return
-
-    env = dict(
-        os.environ, JAX_PLATFORMS="cpu",
-        XLA_FLAGS=f"--xla_force_host_platform_device_count={args.devices}",
-        PYTHONPATH=os.pathsep.join(
-            p for p in ("src", os.environ.get("PYTHONPATH", "")) if p))
-    cmd = [sys.executable, "-m", "benchmarks.distributed_bench", "--_child",
-           "--devices", str(args.devices), "--json", args.json]
-    if args.fast:
-        cmd.append("--fast")
-    out = subprocess.run(cmd, env=env)
-    sys.exit(out.returncode)
-
-
-if __name__ == "__main__":
-    main()

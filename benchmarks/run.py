@@ -14,9 +14,7 @@ cycles/byte-equivalent) so the perf trajectory has a committed baseline.
   hasher  -- Hasher object API vs legacy free functions (overhead ~0)
   tree    -- tree fingerprints (hash.tree): leaf-launch throughput, fold
             tail, digest rate vs the serial stream_digest_host baseline
-  distributed -- shard_map scale-out engine vs single-device (live devices;
-            see benchmarks/distributed_bench.py --devices N for a forced
-            multi-device run emitting BENCH_distributed.json)
+  distributed -- shard_map scale-out engine vs single-device (live devices)
   quality -- per-row-keyed family evaluation rate of the hash-quality
             battery (repro.quality)
   roofline-- dry-run roofline terms (if results/dryrun exists)
@@ -27,6 +25,7 @@ Flags: --fast (CI smoke sizes), --json PATH (default BENCH_kernels.json),
 from __future__ import annotations
 
 import argparse
+import os
 import sys
 import traceback
 
@@ -107,4 +106,8 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     main()

@@ -12,7 +12,7 @@ Row families:
   tree/stream/<T>      -- TreeStream incremental absorb+digest (report-only)
   tree/stream_host/<T> -- the pre-tree serial two-level host loop on the
                           same input (report-only baseline; the D-scaling
-                          comparison rows live in BENCH_distributed.json)
+                          rows come from the `distributed` module)
 """
 from __future__ import annotations
 

@@ -22,6 +22,7 @@ import os
 import re
 import shutil
 import time
+import zipfile
 
 import jax
 import jax.numpy as jnp
@@ -199,32 +200,37 @@ class Checkpointer:
         return ok
 
     def _verify_uncached(self, step: int) -> bool:
+        # Only unreadable or undecodable files count as corruption, so only
+        # the reads sit in the handlers. Hashing runs outside them: an error
+        # there (a fingerprint kernel that fails to lower, a retired
+        # manifest scheme) propagates instead of passing for a corrupt file.
+        unreadable = (OSError, KeyError, ValueError, zipfile.BadZipFile)
         path = os.path.join(self.dir, f"step_{step}")
         try:
             with open(os.path.join(path, "manifest.json")) as f:
                 manifest = json.load(f)
             scheme = manifest.get("scheme", _SCHEME_LEGACY)
+            leaves = manifest["leaves"]
             data = np.load(os.path.join(path, "arrays.npz"))
-            pairs = []
-            for leaf_path, meta in manifest["leaves"].items():
-                got = _leaf_fingerprint(data[meta["key"]], scheme)
-                if f"{got:016x}" != meta["fingerprint"]:
-                    return False
-                pairs.append((leaf_path, got))
-            if "root" in manifest:
-                # pytree-level check: catches manifest edits that permute
-                # or relabel individually-intact leaves
-                root = root_of_leaf_fingerprints(pairs)
-                if f"{root:016x}" != manifest["root"]:
-                    return False
-            return True
-        except UnsupportedManifestScheme:
-            # not mere corruption: the bits may be fine but this process
-            # cannot prove it -- surface the actionable error to verify()
-            # callers instead of a silent False
-            raise
-        except Exception:
+        except unreadable:
             return False
+        pairs = []
+        for leaf_path, meta in leaves.items():
+            try:
+                arr, want = data[meta["key"]], meta["fingerprint"]
+            except unreadable:
+                return False
+            got = _leaf_fingerprint(arr, scheme)
+            if f"{got:016x}" != want:
+                return False
+            pairs.append((leaf_path, got))
+        if "root" in manifest:
+            # pytree-level check: catches manifest edits that permute or
+            # relabel individually-intact leaves
+            root = root_of_leaf_fingerprints(pairs)
+            if f"{root:016x}" != manifest["root"]:
+                return False
+        return True
 
     def latest_valid(self) -> int | None:
         """Newest checkpoint whose every fingerprint verifies -- corrupt or

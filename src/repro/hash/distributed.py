@@ -41,7 +41,6 @@ import warnings
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core import limbs
@@ -170,12 +169,12 @@ class ShardedHasher:
         # jitted shard_map surfaces, built once: the hasher rides as a pytree
         # OPERAND (replicated in_spec), so capacity growth / new key material
         # never invalidates these traces beyond normal shape retraces.
-        self._fn = jax.jit(shard_map(
+        self._fn = jax.jit(jax.shard_map(
             lambda hs, t: hs(t), mesh=self.mesh,
-            in_specs=(P(), P(ax)), out_specs=P(ax), check_rep=False))
-        self._fn_len = jax.jit(shard_map(
+            in_specs=(P(), P(ax)), out_specs=P(ax), check_vma=False))
+        self._fn_len = jax.jit(jax.shard_map(
             lambda hs, t, l: hs(t, l), mesh=self.mesh,
-            in_specs=(P(), P(ax), P(ax)), out_specs=P(ax), check_rep=False))
+            in_specs=(P(), P(ax), P(ax)), out_specs=P(ax), check_vma=False))
         self._ids_fns: dict = {}
 
     @property
@@ -239,9 +238,9 @@ class ShardedHasher:
             else:
                 body = lambda hs, t: hs.shard_ids(t, n_shards)  # noqa: E731
                 specs = (P(), P(ax))
-            fn = self._ids_fns[key] = jax.jit(shard_map(
+            fn = self._ids_fns[key] = jax.jit(jax.shard_map(
                 body, mesh=self.mesh, in_specs=specs, out_specs=P(ax),
-                check_rep=False))
+                check_vma=False))
         toks = jnp.asarray(tokens)
         batch_shape, N = toks.shape[:-1], toks.shape[-1]
         toks_p, lens_p, B = self._pad_rows(toks.reshape((-1, N)), lengths)
@@ -270,9 +269,9 @@ class ShardedHasher:
             else:
                 body = lambda hs, t: hs.probe_indices(t, plan)  # noqa: E731
                 specs = (P(), P(ax))
-            fn = self._ids_fns[key] = jax.jit(shard_map(
+            fn = self._ids_fns[key] = jax.jit(jax.shard_map(
                 body, mesh=self.mesh, in_specs=specs, out_specs=P(ax),
-                check_rep=False))
+                check_vma=False))
         toks = jnp.asarray(tokens)
         batch_shape, N = toks.shape[:-1], toks.shape[-1]
         toks_p, lens_p, B = self._pad_rows(toks.reshape((-1, N)), lengths)
@@ -588,19 +587,19 @@ class DeviceShardedBloom:
             present = _miss_rt(bits, recv_g, recv_row, b) == 0
             return _scatter_rt(bits, recv_g), ~present, overflow[None]
 
-        sm = lambda body, out_specs: jax.jit(shard_map(  # noqa: E731
+        sm = lambda body, out_specs: jax.jit(jax.shard_map(  # noqa: E731
             body, mesh=self.mesh, in_specs=(P(self.axis), P()),
-            out_specs=out_specs, check_rep=False))
+            out_specs=out_specs, check_vma=False))
         self._add = sm(add_body, P(self.axis))
         self._contains = sm(contains_body, P())
         self._admit = sm(admit_body, (P(self.axis), P()))
         # in-graph surfaces: the hasher rides as a replicated pytree operand
         # (like ShardedHasher), tokens/lengths/valid partition over the axis
-        smg = lambda body, out_specs: jax.jit(shard_map(  # noqa: E731
+        smg = lambda body, out_specs: jax.jit(jax.shard_map(  # noqa: E731
             body, mesh=self.mesh,
             in_specs=(P(self.axis), P(), P(self.axis), P(self.axis),
                       P(self.axis)),
-            out_specs=out_specs, check_rep=False))
+            out_specs=out_specs, check_vma=False))
         self._add_dev = smg(add_body_dev, P(self.axis))
         self._contains_dev = smg(contains_body_dev, P())
         self._admit_dev = smg(admit_body_dev, (P(self.axis), P()))

@@ -352,7 +352,7 @@ class Hasher:
         mkb = self._mkb
         K = mkb.n_hashes
         if backend is None:
-            backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
+            backend = default_plan().backend
 
         # Padded width: room for the sentinel + the HM even-pad (DESIGN.md §3).
         n_req = _even(N + 2) if variable_length else _even(N)

@@ -49,7 +49,7 @@ from jax.experimental import pallas as pl
 
 from ..core import gf as gf_core
 from ..core import limbs
-from .multihash import _mask_tile
+from .multihash import _lane_pairs, _mask_tile
 
 U32 = jnp.uint32
 I32 = jnp.int32
@@ -77,8 +77,21 @@ def _clmul_tile(a, b):
 
 
 def _xor_reduce_tile(x):
-    """Row-wise xor fold of a (bb, bn) tile -> (bb,) (associative reduce)."""
-    return jax.lax.reduce(x, jnp.uint32(0), jax.lax.bitwise_xor, (1,))
+    """Row-wise xor fold of a (bb, bn) tile -> (bb,).
+
+    Mosaic lowers no xor reduction, so the fold halves the lane extent
+    with static slices (aligned while the width is a multiple of 256, a
+    few lane shifts below 128); an odd width peels its last column first.
+    """
+    tail = None
+    while x.shape[1] > 1:
+        w = x.shape[1]
+        if w % 2:
+            last = x[:, w - 1 :]
+            tail = last if tail is None else tail ^ last
+            x, w = x[:, : w - 1], w - 1
+        x = x[:, : w // 2] ^ x[:, w // 2 :]
+    return (x if tail is None else x ^ tail)[:, 0]
 
 
 def _gf_multihash_kernel(tok_ref, kl_ref, len_ref, m1_ref, out_ref,
@@ -95,14 +108,14 @@ def _gf_multihash_kernel(tok_ref, kl_ref, len_ref, m1_ref, out_ref,
     tok_eff, live = _mask_tile(toks, len_ref[...], j)
 
     for k in range(n_hashes):
-        kl = jnp.where(live, kl_ref[k][None, :], np.uint32(0))
+        kl = jnp.where(live, kl_ref[k : k + 1, :], np.uint32(0))
         if family == "gf_multilinear":
             p_hi, p_lo = _clmul_tile(kl, tok_eff)
-        else:  # gf_multilinear_hm: pair lanes via lane-contiguous reshape
-            tp = tok_eff.reshape(bb, bn // 2, 2)
-            klp = kl.reshape(bb, bn // 2, 2)
-            p_hi, p_lo = _clmul_tile(klp[:, :, 0] ^ tp[:, :, 0],
-                                     klp[:, :, 1] ^ tp[:, :, 1])
+        else:  # gf_multilinear_hm: (m ^ s) clmul (m' ^ s') over lane pairs
+            left, right, even = _lane_pairs(kl ^ tok_eff)
+            p_hi, p_lo = _clmul_tile(left, right)
+            p_hi = jnp.where(even, p_hi, np.uint32(0))
+            p_lo = jnp.where(even, p_lo, np.uint32(0))
         part_hi = _xor_reduce_tile(p_hi)
         part_lo = _xor_reduce_tile(p_lo)
 
@@ -183,10 +196,10 @@ def gf_multihash_blocks(
         in_specs=[
             pl.BlockSpec((block_b, block_n), lambda i, j: (i, j)),
             pl.BlockSpec((K, block_n), lambda i, j: (0, j)),
-            pl.BlockSpec((block_b,), lambda i, j: (i,)),
+            pl.BlockSpec((block_b, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((K, 2), lambda i, j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((block_b, K, 2), lambda i, j: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, K, 2), U32),
         interpret=interpret,
-    )(tokens.astype(U32), key_lo, lens.astype(I32), m1)
+    )(tokens.astype(U32), key_lo, lens.astype(I32).reshape(B, 1), m1)

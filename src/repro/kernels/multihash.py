@@ -47,6 +47,7 @@ I32 = jnp.int32
 def _mask_tile(toks, lens, j):
     """Apply the per-row length code to the j-th (bb, bn) tile of a row.
 
+    `lens` is the (bb, 1) int32 length-code column of the tile's rows.
     Returns (tok_eff u32, live bool) where `live` masks the key lanes.
     Same algebra as core.hostref._mask_multi, expressed on the tile's
     global column indices; shared by the kernel body and the jnp oracle
@@ -54,7 +55,7 @@ def _mask_tile(toks, lens, j):
     """
     bb, bn = toks.shape
     col = j * bn + jax.lax.broadcasted_iota(I32, (bb, bn), 1)
-    lens = lens.astype(I32)[:, None]
+    lens = lens.astype(I32)
     is_var = lens >= 0
     lm = jnp.where(is_var, lens, -lens - 1)
     tok_eff = jnp.where(
@@ -64,6 +65,19 @@ def _mask_tile(toks, lens, j):
     end = lm + is_var.astype(I32)
     kend = end + (end & 1)  # ceil to even: HM pairs never straddle the mask
     return tok_eff, col < kend
+
+
+def _lane_pairs(x):
+    """HM pairing on lanes: (left, right, even) with left[:, j] = x[:, j],
+    right[:, j] = x[:, j+1] and `even` the (1, bn-1) mask of the lanes j
+    that start a pair. A pair product computed at every lane and masked to
+    the even ones is the paper's (m_2i + s_2i)(m_2i+1 + s_2i+1) term: Mosaic
+    has no lane-strided load and no (bb, bn) -> (bb, bn/2, 2) shape cast,
+    but unit-offset lane slices lower everywhere. Tiles start at even
+    columns (bn is even), so pairs never straddle two tiles."""
+    bn = x.shape[-1]
+    even = (jax.lax.broadcasted_iota(I32, (1, bn - 1), 1) & 1) == 0
+    return x[:, :-1], x[:, 1:], even
 
 
 def _multihash_kernel(tok_ref, kh_ref, kl_ref, len_ref, m1_ref, out_ref,
@@ -79,17 +93,17 @@ def _multihash_kernel(tok_ref, kh_ref, kl_ref, len_ref, m1_ref, out_ref,
     tok_eff, live = _mask_tile(toks, len_ref[...], j)
 
     for k in range(n_hashes):
-        kh = jnp.where(live, kh_ref[k][None, :], np.uint32(0))
-        kl = jnp.where(live, kl_ref[k][None, :], np.uint32(0))
+        kh = jnp.where(live, kh_ref[k : k + 1, :], np.uint32(0))
+        kl = jnp.where(live, kl_ref[k : k + 1, :], np.uint32(0))
         if family in ("multilinear", "multilinear_2x2"):
             p_hi, p_lo = limbs.mul64_u32((kh, kl), tok_eff)
-        else:  # multilinear_hm: pair lanes via lane-contiguous reshape
-            tp = tok_eff.reshape(bb, bn // 2, 2)
-            khp = kh.reshape(bb, bn // 2, 2)
-            klp = kl.reshape(bb, bn // 2, 2)
-            a = limbs.add64_u32((khp[:, :, 0], klp[:, :, 0]), tp[:, :, 0])
-            b = limbs.add64_u32((khp[:, :, 1], klp[:, :, 1]), tp[:, :, 1])
-            p_hi, p_lo = limbs.mul64_low(a, b)
+        else:  # multilinear_hm: (m + s)(m' + s') over lane pairs
+            a_hi, a_lo = limbs.add64_u32((kh, kl), tok_eff)
+            l_hi, r_hi, even = _lane_pairs(a_hi)
+            l_lo, r_lo, _ = _lane_pairs(a_lo)
+            p_hi, p_lo = limbs.mul64_low((l_hi, l_lo), (r_hi, r_lo))
+            p_hi = jnp.where(even, p_hi, np.uint32(0))
+            p_lo = jnp.where(even, p_lo, np.uint32(0))
         part_hi, part_lo = _digit_reduce_mod64(p_hi, p_lo, axis=1)
 
         @pl.when(j == 0)
@@ -171,10 +185,10 @@ def multihash_blocks(
             pl.BlockSpec((block_b, block_n), lambda i, j: (i, j)),
             pl.BlockSpec((K, block_n), lambda i, j: (0, j)),
             pl.BlockSpec((K, block_n), lambda i, j: (0, j)),
-            pl.BlockSpec((block_b,), lambda i, j: (i,)),
+            pl.BlockSpec((block_b, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((K, 2), lambda i, j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((block_b, K, 2), lambda i, j: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, K, 2), U32),
         interpret=interpret,
-    )(tokens.astype(U32), key_hi, key_lo, lens.astype(I32), m1)
+    )(tokens.astype(U32), key_hi, key_lo, lens.astype(I32).reshape(B, 1), m1)

@@ -34,6 +34,7 @@ from jax.experimental import pallas as pl
 from ..core import limbs
 
 U32 = jnp.uint32
+I32 = jnp.int32
 MASK16 = np.uint32(0xFFFF)  # numpy scalar: literal, not a captured const
 
 DEFAULT_BLOCK_B = 8
@@ -45,10 +46,19 @@ def _digit_reduce_mod64(p_hi, p_lo, axis):
 
     Requires the reduced extent <= 2^16 (checked by callers via block_n).
     Returns (hi, lo) uint32 with the axis removed.
+
+    The sums run in int32 through bitcasts (Mosaic reduces signed 32-bit
+    integers only): two's-complement adds wrap mod 2^32 exactly like
+    uint32 ones, and each digit sum is < 2^32, so reading the int32 sum
+    back as uint32 is exact.
     """
-    hi_sum = jnp.sum(p_hi, axis=axis, dtype=U32)              # wraps mod 2^32: correct
-    lo_low = jnp.sum(p_lo & MASK16, axis=axis, dtype=U32)     # exact (< 2^32)
-    lo_high = jnp.sum(p_lo >> 16, axis=axis, dtype=U32)       # exact (< 2^32)
+    def _sum(x):
+        s = jnp.sum(jax.lax.bitcast_convert_type(x, I32), axis=axis)
+        return jax.lax.bitcast_convert_type(s, U32)
+
+    hi_sum = _sum(p_hi)                                        # wraps mod 2^32: correct
+    lo_low = _sum(p_lo & MASK16)                               # exact (< 2^32)
+    lo_high = _sum(p_lo >> 16)                                 # exact (< 2^32)
     lo = lo_low + (lo_high << 16)                              # may wrap: track carry
     carry = (lo < lo_low).astype(U32)
     hi = hi_sum + (lo_high >> 16) + carry

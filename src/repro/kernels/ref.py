@@ -44,7 +44,8 @@ def multihash_ref(tokens, key_hi, key_lo, lens, m1, family="multilinear",
     K = key_hi.shape[0]
     # one "tile" spanning the whole array (j=0) -> same masking algebra as
     # the kernel, single source of truth
-    tok_eff, live = _mask_tile(toks, jnp.asarray(lens), jnp.int32(0))
+    tok_eff, live = _mask_tile(toks, jnp.asarray(lens)[:, None],
+                               jnp.int32(0))
     outs = []
     for k in range(K):
         kh = jnp.where(live, key_hi[k][None, :], np.uint32(0))
@@ -85,7 +86,8 @@ def gf_multihash_ref(tokens, key_lo, lens, m1, family="gf_multilinear",
     toks = jnp.asarray(tokens).astype(jnp.uint32)
     B, N = toks.shape
     K = key_lo.shape[0]
-    tok_eff, live = _mask_tile(toks, jnp.asarray(lens), jnp.int32(0))
+    tok_eff, live = _mask_tile(toks, jnp.asarray(lens)[:, None],
+                               jnp.int32(0))
     outs = []
     for k in range(K):
         kl = jnp.where(live, key_lo[k][None, :], np.uint32(0))

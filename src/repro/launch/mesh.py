@@ -4,12 +4,14 @@ from __future__ import annotations
 
 import jax
 
+from ..parallel.sharding import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips/pod; multi_pod adds the 2-pod leading axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(max_devices: int | None = None):
@@ -20,4 +22,4 @@ def make_host_mesh(max_devices: int | None = None):
     for m in range(1, int(n ** 0.5) + 1):
         if n % m == 0:
             best = (n // m, m)
-    return jax.make_mesh(best, ("data", "model"))
+    return make_mesh(best, ("data", "model"))

@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -67,8 +66,8 @@ def hierarchical_psum(x, mesh, *, pod_axis="pod", inner_axis="data"):
         def body(xs):
             return jax.lax.psum(xs, inner_axis)
 
-        return shard_map(body, mesh=mesh, in_specs=P(inner_axis),
-                         out_specs=P(), check_rep=False)(x)
+        return jax.shard_map(body, mesh=mesh, in_specs=P(inner_axis),
+                             out_specs=P(), check_vma=False)(x)
 
     def body(xs):
         # xs: local shard (per (pod, data) combo)
@@ -77,8 +76,8 @@ def hierarchical_psum(x, mesh, *, pod_axis="pod", inner_axis="data"):
         reduced = jax.lax.psum(scattered, pod_axis)
         return jax.lax.all_gather(reduced, inner_axis, axis=0, tiled=True)
 
-    return shard_map(body, mesh=mesh, in_specs=P((pod_axis, inner_axis)),
-                     out_specs=P(None), check_rep=False)(x)
+    return jax.shard_map(body, mesh=mesh, in_specs=P((pod_axis, inner_axis)),
+                         out_specs=P(None), check_vma=False)(x)
 
 
 def error_feedback_compress(grads, residual, seed: int = 0):

@@ -18,17 +18,12 @@ import re
 import threading
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 _STATE = threading.local()
 
 
 def current_mesh() -> Mesh | None:
-    m = getattr(_STATE, "mesh", None)
-    if m is not None:
-        return m
-    # fall back to the ambient `with mesh:` context
-    env = jax.sharding.get_abstract_mesh() if hasattr(jax.sharding, "get_abstract_mesh") else None
     return getattr(_STATE, "mesh", None)
 
 
@@ -264,6 +259,16 @@ def batch_sharding(mesh: Mesh, ndim: int):
     return NamedSharding(mesh, P(names, *([None] * (ndim - 1))))
 
 
+def make_mesh(axis_shapes, axis_names, *, devices=None) -> Mesh:
+    """`jax.make_mesh` with Auto axes. The rules above place arrays with
+    explicit shardings and let GSPMD propagate the rest; `jax.make_mesh`'s
+    default Explicit axes would instead type every sharded array and reject
+    slices and gathers whose output sharding is ambiguous."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
+
+
 def data_mesh(max_devices: int | None = None) -> Mesh:
     """1-D ('data',) mesh over the live device set -- the scale-out substrate
     for `repro.hash.distributed` (FUNCTION, not constant: importing never
@@ -272,7 +277,7 @@ def data_mesh(max_devices: int | None = None) -> Mesh:
     n = len(jax.devices())
     if max_devices is not None:
         n = min(n, max_devices)
-    return jax.make_mesh((n,), ("data",))
+    return make_mesh((n,), ("data",))
 
 
 def mesh_axis_size(mesh: Mesh, name: str) -> int:

@@ -17,7 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..hash import Hasher, HashSpec
+from ..hash import Hasher, HashSpec, ProbeBucketOverflow
+from ..hash.service import TransportError
 
 _PREFIX_KEY_SEED = 0x1E53
 
@@ -164,9 +165,11 @@ class ServeEngine:
         """Admission-check one slot-pool's worth of pending requests through
         the `AdmissionService` (L1/L2 filters + retry/breaker). Called with
         the NEXT wave while the current decode step is still in flight, so
-        L2 round-trips overlap device compute. Never raises: an admission
-        outage the service itself could not absorb falls back to serving
-        everything (the engine's job is to answer requests)."""
+        L2 round-trips overlap device compute. An admission outage the
+        service itself could not absorb (its own transport or probe-bucket
+        errors) falls back to serving everything -- the engine's job is to
+        answer requests. Any other error (a kernel that fails to lower, a
+        bad argument) propagates: it is a bug, not an outage."""
         if self.admission is None:
             return
         todo = [r for r in reqs if r.admitted is None]
@@ -175,7 +178,7 @@ class ServeEngine:
         try:
             mask = self.admission.admit_batch(
                 [r.prompt.astype(np.uint32) for r in todo])
-        except Exception:
+        except (TransportError, ProbeBucketOverflow):
             self.stats["admission_errors"] += 1
             for r in todo:
                 r.admitted = True

@@ -290,3 +290,39 @@ def test_leaf_fingerprint_rejects_retired_scheme():
     for scheme in ("stream-v0", "banana-v9"):
         with pytest.raises(UnsupportedManifestScheme):
             _leaf_fingerprint(arr, scheme)
+
+
+@pytest.mark.parametrize("exc", [RuntimeError, ValueError])
+def test_fingerprint_failure_propagates_not_corrupt(tmp_path, monkeypatch, exc):
+    """A hashing failure (say, a fingerprint kernel that fails to lower) is
+    an error, not a corrupt checkpoint: verify/latest_valid raise it instead
+    of silently resuming from nothing -- even when its type is one a
+    corrupt file's read could also raise."""
+    from repro.checkpoint import checkpointer as ckmod
+
+    ck = Checkpointer(str(tmp_path))
+    ck.save(1, _state(1))
+
+    def broken(arr, scheme):
+        raise exc("Mosaic failed to compile TPU kernel (simulated)")
+
+    monkeypatch.setattr(ckmod, "_leaf_fingerprint", broken)
+    with pytest.raises(exc, match="Mosaic"):
+        ck.verify(1)
+    with pytest.raises(exc, match="Mosaic"):
+        ck.latest_valid()
+    monkeypatch.undo()
+    assert ck.verify(1) and ck.latest_valid() == 1  # nothing was cached
+
+
+def test_unreadable_files_still_count_as_corrupt(tmp_path):
+    ck = Checkpointer(str(tmp_path))
+    ck.save(1, _state(1))
+    with open(os.path.join(str(tmp_path), "step_1", "manifest.json"), "w") as f:
+        f.write("{not json")
+    assert not ck.verify(1)
+    ck.save(2, _state(2))
+    open(os.path.join(str(tmp_path), "step_2", "arrays.npz"), "wb").write(
+        b"PK\x03\x04 truncated")
+    assert not ck.verify(2)
+    assert ck.latest_valid() is None

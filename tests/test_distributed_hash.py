@@ -18,6 +18,7 @@ import pytest
 
 from repro.data.dedup import BloomFilter
 from repro.hash import DeviceShardedBloom, Hasher, HashSpec, ShardedHasher
+from repro.parallel.sharding import data_mesh
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 RNG = np.random.Generator(np.random.Philox(key=np.uint64(0xD15)))
@@ -249,7 +250,7 @@ def test_exact_dedup_mesh_path_matches():
     from repro.data.dedup import ExactDedup
 
     docs = _ragged(64, 12) * 2  # force duplicates
-    plain, meshed = ExactDedup(), ExactDedup(mesh=jax.make_mesh((1,), ("data",)))
+    plain, meshed = ExactDedup(), ExactDedup(mesh=data_mesh(1))
     np.testing.assert_array_equal(plain.check_and_add_batch(docs),
                                   meshed.check_and_add_batch(docs))
 
@@ -261,7 +262,7 @@ def test_pipeline_mesh_path_matches():
                          shard_id=1)
     docs = _ragged(80, 12)
     plain = HashPipeline(cfg)
-    meshed = HashPipeline(cfg, mesh=jax.make_mesh((1,), ("data",)))
+    meshed = HashPipeline(cfg, mesh=data_mesh(1))
     assert plain.admit_batch(docs) == meshed.admit_batch(docs)
     assert plain.stats == meshed.stats
 
@@ -322,7 +323,6 @@ def test_multi_device_bit_identity_and_bloom():
                                       np.asarray(hostmod.bits))
         # Barrett digit reduction under shard_map: edge moduli incl. m=1,
         # pow2 and 2^32-1 stay bit-identical to numpy's uint64 %
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core.limbs import ModPlan, mod_u64
         from repro.parallel.sharding import data_mesh
@@ -333,7 +333,7 @@ def test_multi_device_bit_identity_and_bloom():
         mesh = data_mesh()
         for m in (1, 2, 97, 1024, 2**31 + 1, 2**32 - 1):
             plan = ModPlan.for_modulus(m)
-            fn = jax.jit(shard_map(
+            fn = jax.jit(jax.shard_map(
                 lambda a, b: mod_u64((a, b), plan), mesh=mesh,
                 in_specs=(P("data"), P("data")), out_specs=P("data")))
             np.testing.assert_array_equal(

@@ -24,13 +24,10 @@ def _run(code, n_dev=8):
     return out.stdout
 
 
-@pytest.mark.xfail(
-    reason="pre-existing: the lowered train cell differentiates through the "
-           "remat optimization_barrier (unimplemented autodiff rule); "
-           "quarantined so CI is green-on-seed")
 def test_train_cell_lowers_and_compiles():
     out = _run("""
         import jax, jax.numpy as jnp, math
+        from repro.parallel.sharding import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config, ShapeSpec
         from repro.models import build
@@ -39,7 +36,7 @@ def test_train_cell_lowers_and_compiles():
         from repro.train.train_state import TrainState, state_shardings
         from repro.launch import hlo_analysis
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_config("yi_34b", smoke=True)
         api = build(cfg)
         opt = make_optimizer(cfg.optimizer, Schedule())
@@ -67,12 +64,13 @@ def test_train_cell_lowers_and_compiles():
 def test_decode_cell_serving_layout():
     out = _run("""
         import jax, jax.numpy as jnp
+        from repro.parallel.sharding import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
         from repro.models import build
         from repro.parallel import sharding as sh
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_config("gemma3_27b", smoke=True)
         api = build(cfg)
         with sh.use_mesh(mesh):
@@ -113,6 +111,7 @@ def test_elastic_checkpoint_reshard():
     """Save on a (4,2) mesh, restore onto (2,2) -- elastic rescale."""
     out = _run("""
         import jax, jax.numpy as jnp, tempfile
+        from repro.parallel.sharding import make_mesh
         from repro.checkpoint import Checkpointer
         from repro.configs import get_config
         from repro.models import build
@@ -123,7 +122,7 @@ def test_elastic_checkpoint_reshard():
         cfg = get_config("mistral_nemo_12b", smoke=True)
         api = build(cfg)
         opt = make_optimizer("adamw", Schedule())
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_a = make_mesh((4, 2), ("data", "model"))
         with sh.use_mesh(mesh_a):
             state = init_state(api, opt, jax.random.key(0))
             state = jax.device_put(state, state_shardings(state, mesh_a))
@@ -131,7 +130,7 @@ def test_elastic_checkpoint_reshard():
         ck = Checkpointer(d)
         ck.save(3, state)
         # elastic restart: fewer devices
-        mesh_b = jax.make_mesh((2, 2), ("data", "model"))
+        mesh_b = make_mesh((2, 2), ("data", "model"))
         with sh.use_mesh(mesh_b):
             like = init_state(api, opt, jax.random.key(1))
             restored = ck.restore(3, like, mesh=mesh_b)

@@ -13,21 +13,6 @@ from repro.models import build
 # full-lane suite: excluded from the CI fast lane (pytest -m "not slow")
 pytestmark = pytest.mark.slow
 
-# Pre-existing seed failure, quarantined (not fixed, not deleted) so CI is
-# green-on-seed and new regressions stand out: reverse-mode autodiff through
-# the remat/scan optimization_barrier in the train path is unimplemented on
-# this jax version. whisper (encdec path, no barrier in its grad) passes and
-# stays a hard assertion.
-_OPT_BARRIER_XFAIL = pytest.mark.xfail(
-    reason="pre-existing: Differentiation rule for 'optimization_barrier' "
-           "not implemented (autodiff through the train-step barrier)")
-_GRAD_BROKEN_ARCHS = frozenset(ARCH_IDS) - {"whisper_large_v3"}
-
-
-def _grad_param(arch):
-    return (pytest.param(arch, marks=_OPT_BARRIER_XFAIL)
-            if arch in _GRAD_BROKEN_ARCHS else arch)
-
 B, T = 2, 16
 
 
@@ -59,7 +44,7 @@ def test_train_loss_finite(arch):
     assert bool(jnp.isfinite(metrics["ce"]))
 
 
-@pytest.mark.parametrize("arch", [_grad_param(a) for a in ARCH_IDS])
+@pytest.mark.parametrize("arch", ARCH_IDS)
 def test_grad_step_finite(arch):
     cfg = get_config(arch, smoke=True)
     api = build(cfg)

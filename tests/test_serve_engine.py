@@ -104,6 +104,50 @@ def test_admission_front_door_rejects_duplicates(engine):
     assert eng.stats["degraded_ticks"] == 0
 
 
+class _RaisingAdmission:
+    """Stand-in admission service whose admit_batch raises `exc`."""
+
+    degraded = False
+    stats = {"l1_only_admits": 0}
+
+    def __init__(self, exc):
+        self.exc = exc
+
+    def admit_batch(self, items):
+        raise self.exc
+
+
+def test_admission_bug_propagates_not_admitted(engine):
+    """A failure that is not an admission outage (a kernel that fails to
+    lower inside the filter) surfaces from submit_all: nothing is admitted
+    and nothing is counted as an admission error."""
+    api, params = engine
+    eng = ServeEngine(api, params, n_slots=2, max_seq=64,
+                      admission=_RaisingAdmission(
+                          RuntimeError("Mosaic lowering failed (simulated)")))
+    reqs = [Request(i, np.arange(6, dtype=np.int32) + i) for i in range(3)]
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        eng.submit_all(reqs)
+    assert all(r.admitted is None and not r.done for r in reqs)
+    assert eng.stats["admission_errors"] == 0
+    assert eng.stats["prefills"] == 0
+
+
+def test_admission_outage_serves_everything(engine):
+    """The service's own transport errors are an outage: the engine counts
+    them and keeps answering requests."""
+    from repro.hash.service import ShardUnavailable
+
+    api, params = engine
+    eng = ServeEngine(api, params, n_slots=2, max_seq=64,
+                      admission=_RaisingAdmission(ShardUnavailable("down")))
+    reqs = [Request(i, np.arange(6, dtype=np.int32) + i, max_new_tokens=3)
+            for i in range(3)]
+    eng.submit_all(reqs)
+    assert all(r.admitted and r.done for r in reqs)
+    assert eng.stats["admission_errors"] >= 1
+
+
 @pytest.mark.slow  # model decode math, not engine/hash behaviour: full lane
 def test_greedy_matches_manual_decode(engine):
     """Engine output == manual prefill+decode loop for a single request."""

@@ -27,9 +27,10 @@ def run_sub(code: str, n_dev: int = 8) -> str:
 def test_param_rules_on_mesh():
     out = run_sub("""
         import jax, json
+        from repro.parallel.sharding import make_mesh
         from jax.sharding import PartitionSpec as P
         from repro.parallel import sharding as sh
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         with sh.use_mesh(mesh):
             # fused attention proj: clean 2D shard
             assert sh.spec_for("blocks/s0/attn/wq/w", (3, 64, 128)) == P(None, "data", "model")
@@ -52,22 +53,19 @@ def test_param_rules_on_mesh():
 
 
 @pytest.mark.slow  # spins a full train step in a subprocess: full lane
-@pytest.mark.xfail(
-    reason="pre-existing: sharded train step differentiates through the "
-           "remat optimization_barrier (unimplemented autodiff rule); "
-           "quarantined so CI is green-on-seed")
 def test_train_step_runs_sharded():
     """One real sharded train step on an 8-device mesh: loss finite, params
     update, shardings preserved."""
     out = run_sub("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.parallel.sharding import make_mesh
         from repro.configs import get_config
         from repro.models import build
         from repro.parallel import sharding as sh
         from repro.train import Schedule, init_state, make_optimizer, make_train_step
         from repro.train.train_state import state_shardings
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_config("granite_moe_1b_a400m", smoke=True)
         api = build(cfg)
         opt = make_optimizer(cfg.optimizer, Schedule(peak_lr=1e-3))
@@ -96,8 +94,9 @@ def test_train_step_runs_sharded():
 def test_hierarchical_psum():
     out = run_sub("""
         import jax, jax.numpy as jnp
+        from repro.parallel.sharding import make_mesh
         from repro.parallel.collectives import hierarchical_psum
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         x = jnp.arange(8.0)
         y = hierarchical_psum(x, mesh, pod_axis="pod", inner_axis="data")
         # psum over pod x data (4 replicas) of the per-shard values:

@@ -17,10 +17,6 @@ from repro.train import Trainer, TrainerConfig
 pytestmark = pytest.mark.slow
 
 
-@pytest.mark.xfail(
-    reason="pre-existing: the train phase differentiates through the remat "
-           "optimization_barrier (unimplemented autodiff rule); quarantined "
-           "so CI is green-on-seed")
 def test_full_system_path(tmp_path):
     cfg = get_config("mistral_nemo_12b", smoke=True)
     api = build(cfg)

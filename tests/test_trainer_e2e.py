@@ -14,14 +14,6 @@ from repro.train import SimulatedFault, Trainer, TrainerConfig
 # full-lane suite: excluded from the CI fast lane (pytest -m "not slow")
 pytestmark = pytest.mark.slow
 
-# Pre-existing seed failure, quarantined so CI is green-on-seed: training
-# (value_and_grad through the remat barrier) hits the unimplemented
-# optimization_barrier differentiation rule. test_straggler_watchdog does
-# not differentiate and stays a hard assertion.
-_OPT_BARRIER_XFAIL = pytest.mark.xfail(
-    reason="pre-existing: Differentiation rule for 'optimization_barrier' "
-           "not implemented (train step autodiff)")
-
 # dense smoke arch: small-MoE smoke configs learn too slowly for a crisp
 # loss-decrease assertion in few steps (drop patterns dominate early);
 # MoE training itself is covered by test_models_smoke + test_system
@@ -40,7 +32,6 @@ def _batches(vocab, B=4, T=16, seed=0):
         yield {k: jnp.asarray(v) for k, v in b.items()}
 
 
-@_OPT_BARRIER_XFAIL
 def test_loss_decreases(tmp_path):
     api = build(CFG)
     tc = TrainerConfig(total_steps=30, checkpoint_every=100, log_every=1,
@@ -52,7 +43,6 @@ def test_loss_decreases(tmp_path):
     assert losses[-1] < losses[0] * 0.9, losses
 
 
-@_OPT_BARRIER_XFAIL
 def test_fault_recovery_resumes_from_checkpoint(tmp_path):
     api = build(CFG)
     tc = TrainerConfig(total_steps=20, checkpoint_every=5, log_every=1,
@@ -73,7 +63,6 @@ def test_fault_recovery_resumes_from_checkpoint(tmp_path):
     assert int(state.step) == 20  # completed despite the fault
 
 
-@_OPT_BARRIER_XFAIL
 def test_resume_is_deterministic(tmp_path):
     """Same data + same checkpoint => identical params after resume."""
     api = build(CFG)
