@@ -17,7 +17,6 @@ cycles/byte-equivalent) so the perf trajectory has a committed baseline.
   distributed -- shard_map scale-out engine vs single-device (live devices)
   quality -- per-row-keyed family evaluation rate of the hash-quality
             battery (repro.quality)
-  roofline-- dry-run roofline terms (if results/dryrun exists)
 
 Flags: --fast (CI smoke sizes), --json PATH (default BENCH_kernels.json),
 --only mod1,mod2 (subset by name above).
@@ -47,22 +46,10 @@ def main(argv=None) -> None:
     common.ROWS.clear()
     common.JSON_ROWS.clear()
 
-    from types import SimpleNamespace
-
     from . import (distributed_bench, gf_variants, hasher_bench,
                    kernels_bench, multihash_bench, quality_bench,
                    table2_multilinear, table3_common, table4_nh, tree_bench,
                    wordsize)
-
-    def _roofline_run():
-        import os
-
-        if os.path.isdir("results/dryrun"):
-            from . import roofline
-
-            roofline.run()
-        else:
-            print("# roofline: skipped (no results/dryrun)")
 
     modules = {
         "table2": table2_multilinear,
@@ -76,7 +63,6 @@ def main(argv=None) -> None:
         "tree": tree_bench,
         "distributed": distributed_bench,
         "quality": quality_bench,
-        "roofline": SimpleNamespace(run=_roofline_run),
     }
     only = [s for s in args.only.split(",") if s]
     unknown = [s for s in only if s not in modules]

@@ -51,6 +51,9 @@ from .spec import HashSpec
 
 I32 = jnp.int32
 U8 = jnp.uint8
+# host spans of the admission call: TraceMe events on the profiler's host
+# plane (the device's clock), about a microsecond each when tracing is off
+_span = jax.profiler.TraceAnnotation
 
 
 def _bucket_shape(B: int, N: int, D: int) -> "tuple[int, int]":
@@ -395,6 +398,21 @@ class DeviceShardedBloom:
     latter kept as the decision-identity A/B reference and bench baseline;
     every transport is bit-identical to the single-device `BloomFilter` by
     construction.
+
+    `stats` is the operator's view of the in-graph paths, plain ints since
+    construction. `overflow_fallbacks`: routed batches replayed through
+    all_gather. Counted per staged call of `add_batch`, `contains_batch`
+    and `check_and_add_batch` (the legacy `"host"` transport stages
+    nothing): `calls`; `items`, the real rows B; `real_tokens`, the sum of
+    the item lengths; `staged_tokens`, Bp x Np of the pow2 bucket the batch
+    was padded to. `staged_tokens / real_tokens` is the padding waste: the
+    factor by which the staged, copied and hashed bytes exceed the input.
+
+    Spans (`jax.profiler.TraceAnnotation`, seen only under the profiler):
+    `bloom.check_and_add_batch` (metadata `call`, `items`, `width`) holds
+    `bloom.stage`, `bloom.launch`, `bloom.readback` and, on a routed
+    overflow, `bloom.overflow_replay`; `bloom.stage` holds
+    `bloom.stage.stack`, `bloom.stage.pad` and `bloom.stage.to_device`.
     """
 
     _settle_every = 8  # max deferred routed adds before flags materialize
@@ -436,7 +454,8 @@ class DeviceShardedBloom:
         sharding = NamedSharding(self.mesh, P(self.axis))
         self._bits = jax.device_put(jnp.zeros(m_pad, U8), sharding)
         self._pending: list = []  # routed adds with unread overflow flags
-        self.stats = {"overflow_fallbacks": 0}
+        self.stats = {"overflow_fallbacks": 0, "calls": 0, "items": 0,
+                      "real_tokens": 0, "staged_tokens": 0}
 
         m_local, ax, plan = self.m_local, self.axis, self.plan
         transport = self.transport
@@ -644,9 +663,10 @@ class DeviceShardedBloom:
         if not replay:
             return
         self.stats["overflow_fallbacks"] += len(replay)
-        for toks, lens, valid in replay:
-            self._bits = self._add_dev(
-                self._bits, self.sharded.hasher, toks, lens, valid)
+        with _span("bloom.overflow_replay"):
+            for toks, lens, valid in replay:
+                self._bits = self._add_dev(
+                    self._bits, self.sharded.hasher, toks, lens, valid)
         if self.transport.on_overflow == "error":
             raise ProbeBucketOverflow(
                 f"{len(replay)} routed add batch(es) overflowed the static "
@@ -670,20 +690,29 @@ class DeviceShardedBloom:
         Shapes bucket via `_bucket_shape` (the same bounded-trace policy as
         `ShardedHasher.hash_batch`); padding rows are invalid -- their
         probes become the -1 sentinel in-graph."""
-        toks, lens = _stack_ragged(items)
-        B, N = toks.shape
-        if lens is None:
-            lens = np.full(B, N, np.int64)
-        Bp, Np = _bucket_shape(B, N, self.n_shards)
-        toks_p = np.zeros((Bp, Np), np.uint32)
-        toks_p[:B, :N] = toks
-        lens_p = np.zeros(Bp, np.int32)
-        lens_p[:B] = np.asarray(lens, np.int64)
-        valid = np.zeros(Bp, bool)
-        valid[:B] = True
-        self.sharded.ensure(Np)
-        return (jnp.asarray(toks_p), jnp.asarray(lens_p),
-                jnp.asarray(valid), B)
+        with _span("bloom.stage"):
+            with _span("bloom.stage.stack"):
+                toks, lens = _stack_ragged(items)
+            B, N = toks.shape
+            with _span("bloom.stage.pad"):
+                if lens is None:
+                    lens = np.full(B, N, np.int64)
+                Bp, Np = _bucket_shape(B, N, self.n_shards)
+                toks_p = np.zeros((Bp, Np), np.uint32)
+                toks_p[:B, :N] = toks
+                lens_p = np.zeros(Bp, np.int32)
+                lens_p[:B] = np.asarray(lens, np.int64)
+                valid = np.zeros(Bp, bool)
+                valid[:B] = True
+            st = self.stats
+            st["calls"] += 1
+            st["items"] += B
+            st["real_tokens"] += int(lens.sum())
+            st["staged_tokens"] += Bp * Np
+            with _span("bloom.stage.to_device"):
+                self.sharded.ensure(Np)
+                return (jnp.asarray(toks_p), jnp.asarray(lens_p),
+                        jnp.asarray(valid), B)
 
     def owner_shards(self, items) -> np.ndarray:
         """(B,) home shard per item via the Lemire multiply-shift reduction
@@ -755,22 +784,32 @@ class DeviceShardedBloom:
             self._bits, admitted = self._admit(
                 self._bits, jnp.asarray(self._probes(items)))
             return np.asarray(admitted)
-        self._settle()
-        toks, lens, valid, B = self._stage(items)
-        if kind == "all_gather":
-            self._bits, admitted = self._admit_dev(
-                self._bits, self.sharded.hasher, toks, lens, valid)
-            return np.asarray(admitted)[:B]
-        new_bits, admitted, flag = self._admit_rt(
-            self._bits, self.sharded.hasher, toks, lens, valid)
-        if bool(np.asarray(flag).any()):
-            # truncated exchange: discard the partial scatter/verdicts and
-            # rerun against the untouched pre-call bits via all_gather
-            self._overflowed("check_and_add_batch")
-            new_bits, admitted = self._admit_dev(
-                self._bits, self.sharded.hasher, toks, lens, valid)
-        self._bits = new_bits
-        return np.asarray(admitted)[:B]
+        with _span("bloom.check_and_add_batch") as span:
+            self._settle()
+            toks, lens, valid, B = self._stage(items)
+            span.set_metadata(call=self.stats["calls"], items=B,
+                              width=toks.shape[1])
+            args = (self.sharded.hasher, toks, lens, valid)
+            if kind == "all_gather":
+                with _span("bloom.launch"):
+                    self._bits, admitted = self._admit_dev(self._bits, *args)
+                with _span("bloom.readback"):
+                    return np.asarray(admitted)[:B]
+            with _span("bloom.launch"):
+                new_bits, admitted, flag = self._admit_rt(self._bits, *args)
+            with _span("bloom.readback"):
+                overflow = bool(np.asarray(flag).any())
+                if not overflow:
+                    admitted = np.asarray(admitted)[:B]
+            if overflow:
+                # truncated exchange: discard the partial scatter/verdicts
+                # and rerun against the untouched pre-call bits via all_gather
+                with _span("bloom.overflow_replay"):
+                    self._overflowed("check_and_add_batch")
+                    new_bits, admitted = self._admit_dev(self._bits, *args)
+                    admitted = np.asarray(admitted)[:B]
+            self._bits = new_bits
+            return admitted
 
     def _overflowed(self, op: str) -> None:
         self.stats["overflow_fallbacks"] += 1

@@ -202,4 +202,5 @@ def gf_multihash_blocks(
         out_specs=pl.BlockSpec((block_b, K, 2), lambda i, j: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, K, 2), U32),
         interpret=interpret,
+        name="gf_multihash",
     )(tokens.astype(U32), key_lo, lens.astype(I32).reshape(B, 1), m1)

@@ -191,4 +191,5 @@ def multihash_blocks(
         out_specs=pl.BlockSpec((block_b, K, 2), lambda i, j: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, K, 2), U32),
         interpret=interpret,
+        name="multihash",
     )(tokens.astype(U32), key_hi, key_lo, lens.astype(I32).reshape(B, 1), m1)

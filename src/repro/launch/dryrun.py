@@ -9,7 +9,7 @@ Run one cell:   python -m repro.launch.dryrun --arch yi_34b --shape train_4k \
 Run everything: python -m repro.launch.dryrun --all [--mesh both]
 
 Each cell writes results/<arch>__<shape>__<mesh>.json incrementally so a
-driver can resume; benchmarks/roofline.py consumes these files.
+driver can resume.
 """
 import argparse
 import json
