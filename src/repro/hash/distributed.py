@@ -407,12 +407,29 @@ class DeviceShardedBloom:
     the item lengths; `staged_tokens`, Bp x Np of the pow2 bucket the batch
     was padded to. `staged_tokens / real_tokens` is the padding waste: the
     factor by which the staged, copied and hashed bytes exceed the input.
+    `stage_reuses`: staged calls written into the kept bucket buffer;
+    `stage_allocs`: staged calls that had to allocate one (the first call,
+    and every change of bucket shape).
+
+    Host staging keeps ONE (Bp, Np) uint32 bucket buffer, Bp x Np x 4 bytes
+    of host memory (268 MB for a 4096 x 16384 bucket), for the last bucket
+    shape seen: each call writes every item's tokens once, straight into
+    it, and zeroes only what the last call wrote beyond the new lengths, so
+    the staged bytes equal a fresh zero-padded stack. A call with another
+    bucket shape drops it and allocates one of the new shape. The device
+    gets its own copy (`_stage`), so staged arrays held for an overflow
+    replay never see a later call's rewrite.
 
     Spans (`jax.profiler.TraceAnnotation`, seen only under the profiler):
     `bloom.check_and_add_batch` (metadata `call`, `items`, `width`) holds
     `bloom.stage`, `bloom.launch`, `bloom.readback` and, on a routed
-    overflow, `bloom.overflow_replay`; `bloom.stage` holds
-    `bloom.stage.stack`, `bloom.stage.pad` and `bloom.stage.to_device`.
+    overflow, `bloom.overflow_replay`; `bloom.stage` holds, in order,
+    `bloom.stage.stack` (the item lengths, and on a reuse the tokens
+    written into the kept buffer), `bloom.stage.pad` (on a reuse the stale
+    row tails and rows zeroed; on a shape change the new buffer allocated
+    and the tokens written into its fresh pages) and
+    `bloom.stage.to_device` (three host-to-device copies, plus the
+    device-side copy of the tokens).
     """
 
     _settle_every = 8  # max deferred routed adds before flags materialize
@@ -455,7 +472,11 @@ class DeviceShardedBloom:
         self._bits = jax.device_put(jnp.zeros(m_pad, U8), sharding)
         self._pending: list = []  # routed adds with unread overflow flags
         self.stats = {"overflow_fallbacks": 0, "calls": 0, "items": 0,
-                      "real_tokens": 0, "staged_tokens": 0}
+                      "real_tokens": 0, "staged_tokens": 0,
+                      "stage_reuses": 0, "stage_allocs": 0}
+        # the kept bucket buffer, its row views, how far each row was
+        # written, and the device copy of its last contents (`_stage`)
+        self._buf = self._buf_rows = self._buf_lens = self._buf_fence = None
 
         m_local, ax, plan = self.m_local, self.axis, self.plan
         transport = self.transport
@@ -685,23 +706,49 @@ class DeviceShardedBloom:
         return (h % np.uint64(self.m)).astype(np.int32)
 
     def _stage(self, items):
-        """Stack host items for the in-graph path: (Bp, Np) uint32 tokens,
+        """Stage host items for the in-graph path: (Bp, Np) uint32 tokens,
         (Bp,) int32 lengths, (Bp,) bool row-valid mask, true batch size B.
         Shapes bucket via `_bucket_shape` (the same bounded-trace policy as
         `ShardedHasher.hash_batch`); padding rows are invalid -- their
-        probes become the -1 sentinel in-graph."""
+        probes become the -1 sentinel in-graph. Tokens go once into the
+        kept bucket buffer (class docstring): row i holds item i in columns
+        [0, len_i) and zeros elsewhere, as a fresh zero-padded stack would."""
         with _span("bloom.stage"):
             with _span("bloom.stage.stack"):
-                toks, lens = _stack_ragged(items)
-            B, N = toks.shape
-            with _span("bloom.stage.pad"):
-                if lens is None:
-                    lens = np.full(B, N, np.int64)
+                if self._buf_fence is not None:
+                    # the device has its copy of the last call's bytes
+                    # before any of them is overwritten
+                    self._buf_fence.block_until_ready()
+                if isinstance(items, (list, tuple)):
+                    src = [r if isinstance(r, np.ndarray) and r.ndim == 1
+                           else np.atleast_1d(np.asarray(r)) for r in items]
+                    lens = np.fromiter(map(len, src), np.int64, len(src))
+                    N = int(lens.max())
+                else:
+                    src = np.atleast_2d(np.asarray(items))
+                    N = src.shape[1]
+                    lens = np.full(src.shape[0], N, np.int64)
+                B = len(lens)
                 Bp, Np = _bucket_shape(B, N, self.n_shards)
-                toks_p = np.zeros((Bp, Np), np.uint32)
-                toks_p[:B, :N] = toks
+                reuse = self._buf is not None and self._buf.shape == (Bp, Np)
+                if reuse:
+                    self._fill(src)
+            with _span("bloom.stage.pad"):
                 lens_p = np.zeros(Bp, np.int32)
-                lens_p[:B] = np.asarray(lens, np.int64)
+                lens_p[:B] = lens
+                if reuse:
+                    prev = self._buf_lens
+                    stale = np.flatnonzero(prev > lens_p)
+                    views = self._buf_rows
+                    for i, lo, hi in zip(stale.tolist(),
+                                         lens_p[stale].tolist(),
+                                         prev[stale].tolist()):
+                        views[i][lo:hi] = 0
+                else:
+                    self._buf = np.zeros((Bp, Np), np.uint32)
+                    self._buf_rows = list(self._buf)
+                    self._fill(src)
+                self._buf_lens = lens_p
                 valid = np.zeros(Bp, bool)
                 valid[:B] = True
             st = self.stats
@@ -709,10 +756,24 @@ class DeviceShardedBloom:
             st["items"] += B
             st["real_tokens"] += int(lens.sum())
             st["staged_tokens"] += Bp * Np
+            st["stage_reuses" if reuse else "stage_allocs"] += 1
             with _span("bloom.stage.to_device"):
                 self.sharded.ensure(Np)
-                return (jnp.asarray(toks_p), jnp.asarray(lens_p),
-                        jnp.asarray(valid), B)
+                # a runtime may zero-copy host memory (the CPU client does,
+                # for aligned arrays): the device-side copy is what no later
+                # rewrite of the buffer can reach
+                toks = jnp.copy(jax.device_put(self._buf))
+                self._buf_fence = toks
+                return toks, jnp.asarray(lens_p), jnp.asarray(valid), B
+
+    def _fill(self, src) -> None:
+        """Write item tokens into the kept buffer, casting in place: a
+        ragged list row by row, a dense (B, N) array in one assignment."""
+        if isinstance(src, np.ndarray):
+            self._buf[:src.shape[0], :src.shape[1]] = src
+            return
+        for view, row in zip(self._buf_rows, src):
+            view[:len(row)] = row
 
     def owner_shards(self, items) -> np.ndarray:
         """(B,) home shard per item via the Lemire multiply-shift reduction

@@ -77,13 +77,15 @@ def _within(inner, outer) -> bool:
 def test_counters_are_exact_on_every_in_graph_path(transport):
     bloom = _bloom(transport)
     assert bloom.stats == {"overflow_fallbacks": 0, "calls": 0, "items": 0,
-                           "real_tokens": 0, "staged_tokens": 0}
+                           "real_tokens": 0, "staged_tokens": 0,
+                           "stage_reuses": 0, "stage_allocs": 0}
     # three ragged batches and a dense one, all in the (8, 16) bucket
     a, b, c = [3, 1, 13, 7, 2], [5, 9, 4, 1, 1, 2], [16, 2, 6, 6, 1, 11, 3]
     bloom.check_and_add_batch(_ragged(a))
     assert bloom.stats == {"overflow_fallbacks": 0, "calls": 1, "items": 5,
                            "real_tokens": sum(a),
-                           "staged_tokens": _staged(a)}
+                           "staged_tokens": _staged(a),
+                           "stage_reuses": 0, "stage_allocs": 1}
     assert _staged(a) == 8 * 16  # 5 rows -> 8, width 13 -> 16
     bloom.add_batch(_ragged(b))
     bloom.contains_batch(_ragged(c))
@@ -97,13 +99,23 @@ def test_counters_are_exact_on_every_in_graph_path(transport):
     assert bloom.stats["real_tokens"] == sum(a) + sum(b) + sum(c) + 75
     assert bloom.stats["staged_tokens"] == (_staged(a) + _staged(b)
                                             + _staged(c) + _staged([15] * 5))
+    # one bucket so far: every call after the first reused its buffer
+    assert (bloom.stats["stage_reuses"], bloom.stats["stage_allocs"]) == (3, 1)
+    bloom.check_and_add_batch(_ragged([40, 3]))  # the (2, 64) bucket
+    bloom.add_batch(_ragged([33]))               # (1, 64): another shape
+    bloom.contains_batch(_ragged([2, 64]))       # (2, 64) again
+    bloom.check_and_add_batch(_ragged([9, 1]))   # (2, 16)
+    bloom.add_batch(_ragged([12, 10]))           # (2, 16): reused
+    assert (bloom.stats["stage_reuses"], bloom.stats["stage_allocs"]) == (4, 5)
+    assert bloom.stats["calls"] == 9
 
 
 def test_host_transport_stages_nothing():
     bloom = _bloom("host")
     bloom.check_and_add_batch(_ragged([4, 2]))
     assert bloom.stats == {"overflow_fallbacks": 0, "calls": 0, "items": 0,
-                           "real_tokens": 0, "staged_tokens": 0}
+                           "real_tokens": 0, "staged_tokens": 0,
+                           "stage_reuses": 0, "stage_allocs": 0}
 
 
 def test_admission_call_writes_every_span_nested(tmp_path):
