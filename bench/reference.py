@@ -14,11 +14,16 @@ bits and k functions sets, and tests, the bits h_j(s) mod m.
 
 A filter does not start empty: it holds the state of a deployment part-way
 through its corpus, as if `preload_items` random items had been added. Bit
-i of the preloaded state is set iff F(F(i xor s_1) + s_2) < p * 2^32, where
-F is MurmurHash3's 32-bit finalizer, (s_1, s_2) come from the run seed and
-p = 1 - exp(-k * preload_items / m) is the share of bits such a filter
-holds. The benchmark writes that state into the program's filter; the
-reference computes it from this definition, at the positions it reads.
+i (any position below 2^64) of the preloaded state is set iff
+
+    F(F(lo xor s_1) + s_2 + hi * 0x9E3779B9) < p * 2^32    (mod 2^32),
+
+where lo = i mod 2^32 and hi = floor(i / 2^32) are the position's two
+32-bit words, F is MurmurHash3's 32-bit finalizer, (s_1, s_2) come from
+the run seed and p = 1 - exp(-k * preload_items / m) is the share of bits
+such a filter holds. Below 2^32 (hi = 0) that is F(F(i xor s_1) + s_2).
+The benchmark writes that state into the program's filter; the reference
+computes it from this definition, at the positions it reads.
 
 The control computes the same hash in the precision below (a 32-bit
 accumulator: every product and sum mod 2^32), the step a faster kernel
@@ -31,6 +36,7 @@ import math
 import numpy as np
 
 GOLDEN64 = 0x9E3779B97F4A7C15
+HI_MIX = 0x9E3779B9  # weight of a position's high word in the preload
 U64 = np.uint64
 
 
@@ -106,17 +112,22 @@ def preload_params(cfg: dict, seed: int) -> "tuple[int, int, int]":
 
 
 def preloaded(cfg: dict, seed: int, pos: np.ndarray) -> np.ndarray:
-    """Whether the preloaded state holds the bits at positions `pos` < m."""
+    """Whether the preloaded state holds the bits at int64 positions `pos`
+    (any position from 0 to 2^63; the state holds none at or past m)."""
     s1, s2, thr = preload_params(cfg, seed)
-    h = fmix32(np.asarray(pos, np.int64).astype(np.uint32) ^ np.uint32(s1))
+    pos = np.asarray(pos, np.int64)
+    lo = (pos & 0xFFFFFFFF).astype(np.uint32)
+    hi = (pos >> 32).astype(np.uint32)
+    h = fmix32(lo ^ np.uint32(s1))
     with np.errstate(over="ignore"):
-        h = fmix32(h + np.uint32(s2))
+        h = fmix32(h + np.uint32(s2) + hi * np.uint32(HI_MIX))
     return h < np.uint32(thr)
 
 
 class Bloom:
     """Bloom filter of m bits and k functions over token strings, from the
-    preloaded state `base(pos)` (empty where None)."""
+    preloaded state `base(pos)` (empty where None). The bits set since the
+    preload are kept as a sorted int64 array, so that m may be any size."""
 
     def __init__(self, m: int, k: int, seed: int, control: bool = False,
                  base=None):
@@ -124,13 +135,20 @@ class Bloom:
         self.keys = Keys(seed, k)
         self.control = control
         self.base = base
-        self.bits = np.zeros(self.m, bool)  # bits set since the preload
+        self.held = np.zeros(0, np.int64)  # bits set since the preload
 
     def probes(self, rows) -> np.ndarray:
         return probes(self.keys, rows, self.m, self.control)
 
+    def _added(self, pos: np.ndarray) -> np.ndarray:
+        if not len(self.held):
+            return np.zeros(pos.shape, bool)
+        i = np.minimum(np.searchsorted(self.held, pos), len(self.held) - 1)
+        return self.held[i] == pos
+
     def bits_at(self, pos: np.ndarray) -> np.ndarray:
-        held = self.bits[pos]
+        pos = np.asarray(pos, np.int64)
+        held = self._added(pos)
         return held if self.base is None else held | self.base(pos)
 
     def check_and_add(self, rows) -> np.ndarray:
@@ -140,7 +158,10 @@ class Bloom:
             return np.zeros(0, bool)
         p = self.probes(rows)
         new = ~self.bits_at(p).all(axis=1)
-        self.bits[p.ravel()] = True
+        fresh = np.unique(p)
+        fresh = fresh[~self._added(fresh)]
+        self.held = np.insert(self.held, np.searchsorted(self.held, fresh),
+                              fresh)
         return new
 
 
