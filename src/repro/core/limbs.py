@@ -148,7 +148,7 @@ def u64_to_numpy(a):
 
 
 # ---------------------------------------------------------------------------
-# 64-mod-m digit reduction (DESIGN.md §2): h mod m for arbitrary 32-bit m,
+# 64-mod-m digit reduction (DESIGN.md §2): h mod m for any m < 2^63,
 # entirely in 32-bit ops, so Bloom probe indices never leave the device.
 # ---------------------------------------------------------------------------
 
@@ -157,16 +157,19 @@ def u64_to_numpy(a):
 class ModPlan:
     """Frozen per-modulus aux for `mod_u64`/`mw_mod`.
 
-    Carries the Barrett reciprocal M = floor(2^96 / m) + 1 as three uint32
-    limbs (little-endian). All fields are python ints: the plan is hashable
+    Carries the Barrett reciprocal M = floor(2^N / m) + 1 as three uint32
+    limbs (little-endian), with N = 96 for m < 2^32 and N = 128 for
+    m >= 2^32 (`wide`). All fields are python ints: the plan is hashable
     (jit static argument / kernel closure) and the limbs enter traced code
     as numpy-scalar literals, never captured array constants.
 
-    Why 96 bits: for x < 2^F and m < 2^L the reciprocal at N = F + L bits
-    makes the floor-division estimate EXACT (see `mod_u64`); with F = 64,
-    L = 32 that is N = 96, so M fits three limbs for every non-power-of-two
-    m >= 3 (M <= 2^96/3 + 1 < 2^95). Powers of two (including m = 1) take
-    the mask fast path and never consult M.
+    Why these widths: for x < 2^F and m < 2^L the reciprocal at N = F + L
+    bits makes the floor-division estimate EXACT (see `mod_u64`); with
+    F = 64, L = 32 that is N = 96, so M fits three limbs for every
+    non-power-of-two m >= 3 (M <= 2^96/3 + 1 < 2^95). A wide plan takes
+    L = 64 (N = 128), and M still fits three limbs: m >= 2^32 + 1 gives
+    M <= 2^128/(2^32 + 1) + 1 < 2^96. Powers of two (including m = 1)
+    take the mask fast path and never consult M.
     """
 
     m: int
@@ -174,40 +177,79 @@ class ModPlan:
     mu0: int
     mu1: int
     mu2: int
+    wide: bool = False  # m >= 2^32: the residue is two limbs (hi, lo)
 
     @classmethod
     def for_modulus(cls, m: int) -> "ModPlan":
         m = int(m)
-        if not 1 <= m < 1 << 32:
-            raise ValueError(f"modulus {m} outside the 32-bit domain [1, 2^32)")
+        if not 1 <= m < 1 << 63:
+            raise ValueError(f"modulus {m} outside the domain [1, 2^63)")
+        wide = m >= 1 << 32
         if m & (m - 1) == 0:
-            return cls(m=m, is_pow2=True, mu0=0, mu1=0, mu2=0)
-        mu = (1 << 96) // m + 1
+            return cls(m=m, is_pow2=True, mu0=0, mu1=0, mu2=0, wide=wide)
+        mu = (1 << (128 if wide else 96)) // m + 1
         return cls(m=m, is_pow2=False, mu0=mu & 0xFFFFFFFF,
-                   mu1=(mu >> 32) & 0xFFFFFFFF, mu2=mu >> 64)
+                   mu1=(mu >> 32) & 0xFFFFFFFF, mu2=mu >> 64, wide=wide)
+
+
+def _mul_limbs(a, b, n_out: int):
+    """Low `n_out` limbs of the product of little-endian uint32 limb
+    tuples `a` and `b` (schoolbook, carries propagated row by row: a
+    column's acc + a_i*b_j + carry <= 2^64 - 1, so each carry fits a
+    limb)."""
+    zero = jnp.zeros_like(_u32(a[0]) + _u32(b[0]))
+    acc = [zero] * n_out
+    for i, ai in enumerate(a):
+        carry = zero
+        for j, bj in enumerate(b[: n_out - i]):
+            hi, lo = mul32_full(ai, bj)
+            s = acc[i + j] + lo
+            c1 = (s < lo).astype(U32)
+            t = s + carry
+            c2 = (t < carry).astype(U32)
+            acc[i + j] = t
+            carry = hi + c1 + c2
+        if i + len(b) < n_out:
+            acc[i + len(b)] = carry
+    return acc
 
 
 def mod_u64(a, plan: ModPlan):
-    """(hi, lo) uint32 64-bit value mod `plan.m` -> uint32 residue (< m).
+    """(hi, lo) uint32 64-bit value mod `plan.m` -> uint32 residue (< m),
+    or, under a `wide` plan (m >= 2^32), the residue as (hi, lo) limbs.
 
-    Power-of-two m: ``lo & (m-1)`` (m divides 2^32, the hi limb vanishes).
+    Power-of-two m: ``lo & (m-1)`` (m divides 2^32, the hi limb vanishes);
+    wide, ``(hi & (m/2^32 - 1), lo)``.
 
     Otherwise the Lemire/Barrett direct-remainder form on 16-bit digits
     (every multiply below is `mul32_full`, i.e. four native 16-bit-digit
-    multiplies): with M = floor(2^96/m) + 1,
+    multiplies): with M = floor(2^N/m) + 1,
 
-        L = (M * x) mod 2^96          # fractional part of x/m, 96-bit fixed
-        r = floor(m * L / 2^96)       # scale the fraction back by m
+        L = (M * x) mod 2^N           # fractional part of x/m, N-bit fixed
+        r = floor(m * L / 2^N)        # scale the fraction back by m
 
     EXACTNESS (the correction-step bound, DESIGN.md §2): write
-    2^96 = k*m + rho (0 < rho < m, m not a power of two) so M = k + 1 and
-    M*x = (2^96*x + b*x)/m with b = m - rho in [1, m-1]. Then
-    L/2^96 = (x mod m)/m + b*x/(m*2^96), and the error term obeys
-    b*x < m * 2^64 <= 2^96, hence m*L/2^96 < (x mod m) + 1 and the floor
-    IS the remainder -- the classic Barrett q-estimate correction step is
-    provably never needed at this reciprocal width.
+    2^N = k*m + rho (0 < rho < m, m not a power of two) so M = k + 1 and
+    M*x = (2^N*x + b*x)/m with b = m - rho in [1, m-1]. Then
+    L/2^N = (x mod m)/m + b*x/(m*2^N), and the error term obeys
+    b*x < m * 2^64 <= 2^N -- for N = 96 because m < 2^32, for N = 128
+    because m < 2^64 -- hence m*L/2^N < (x mod m) + 1 and the floor IS
+    the remainder: the classic Barrett q-estimate correction step is
+    provably never needed at either reciprocal width.
+
+    Below 2^32 (N = 96) the limb products are written out by hand; a wide
+    plan runs the generic `_mul_limbs` twice (L: 3 x 2 limbs truncated to
+    4; r: limbs 4 and 5 of the 2 x 4-limb product m * L).
     """
     hi, lo = _u32(a[0]), _u32(a[1])
+    if plan.wide:
+        if plan.is_pow2:
+            return hi & np.uint32((plan.m >> 32) - 1), lo
+        mu = (np.uint32(plan.mu0), np.uint32(plan.mu1), np.uint32(plan.mu2))
+        frac = _mul_limbs((lo, hi), mu, 4)
+        m2 = (np.uint32(plan.m & 0xFFFFFFFF), np.uint32(plan.m >> 32))
+        r = _mul_limbs(m2, frac, 6)
+        return r[5], r[4]
     if plan.is_pow2:
         return lo & np.uint32(plan.m - 1)
     m32 = np.uint32(plan.m)
@@ -246,8 +288,10 @@ def mw_mod(a, plan: ModPlan):
     residue r < m makes every step value r*2^32 + limb < m*2^32 <= 2^64,
     i.e. exactly one `mod_u64` per limb. (Power-of-two m degenerates to
     ``a[0] & (m-1)`` through the same loop: each step discards r because
-    m divides 2^32.)
+    m divides 2^32.) A wide plan breaks that bound and is refused.
     """
+    if plan.wide:
+        raise ValueError(f"mw_mod needs a modulus below 2^32, got {plan.m}")
     r = jnp.zeros_like(_u32(a[-1]))
     for limb in reversed(a):
         r = mod_u64((r, limb), plan)

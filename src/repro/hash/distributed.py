@@ -27,9 +27,12 @@ How probes move between devices is a first-class `ProbeTransport` spec
 device's (B/D, k) probe indices by owning bit range and exchanges ONLY the
 owned probes with one `jax.lax.all_to_all` (~1/D the bytes of the
 `"all_gather"` transport, which replicates the full (B, k) matrix);
-`"host"` replays the legacy per-batch host round-trip.  All three are
-bit-identical to the single-device `BloomFilter` -- the transport moves
-the same global probe set, never changes it.  Collective layout: `add` is
+`"host"` replays the legacy per-batch host round-trip.  Every transport
+moves each probe as an (owner, local) int32 pair -- its shard and its
+index inside the shard -- so m may pass 2^32 while each shard stays
+below 2^31 bits.  All three are bit-identical to the single-device
+`BloomFilter` -- the transport moves the same global probe set, never
+changes it.  Collective layout: `add` is
 one fused launch with zero psums and ZERO host syncs; `contains` and the
 fused `check_and_add_batch` admission add exactly ONE psum.
 """
@@ -77,18 +80,21 @@ class ProbeBucketOverflow(RuntimeError):
 
 @dataclasses.dataclass(frozen=True)
 class ProbeTransport:
-    """How `DeviceShardedBloom` moves probe indices between devices.
+    """How `DeviceShardedBloom` moves probes between devices. A probe is
+    an (owner, local) int32 pair on every transport: the shard that holds
+    its bit and the bit's index inside that shard.
 
     kinds (all three bit-identical to the single-device `BloomFilter`):
-      "routed"      default -- bucket each device's (B/D, k) probes by owning
-                    bit range and exchange ONLY owned probes with one
+      "routed"      default -- bucket each device's (B/D, k) probes by
+                    owner and exchange ONLY (local, row) pairs with one
                     `jax.lax.all_to_all` (~capacity_factor/D the bytes of
                     all_gather); per-item verdicts come back via ONE psum of
-                    scatter-added miss counts keyed by global row id.
-      "all_gather"  replicate the full (B, k) probe matrix to every device
-                    (the PR 5 layout; what `in_graph_mod=True` meant).
+                    per-row miss counts keyed by global row id.
+      "all_gather"  replicate the full (B, k) pair matrix to every device
+                    (what `in_graph_mod=True` meant).
       "host"        legacy per-batch host round-trip: hash_batch -> numpy
-                    `h % m` -> replicated operand (`in_graph_mod=False`).
+                    `h % m` -> (owner, local) pairs -> replicated operand
+                    (`in_graph_mod=False`).
 
     Bucket capacity is static (jit needs fixed shapes): each destination
     receives at most `capacity(P, D)` of a device's P = (B/D)*k probes.
@@ -253,7 +259,8 @@ class ShardedHasher:
 
     def probe_indices(self, tokens, plan, lengths=None):
         """Sharded twin of `Hasher.probe_indices`: (..., N) tokens ->
-        (..., K) uint32 Bloom probe indices in [0, m), each device reducing
+        (..., K) uint32 Bloom probe indices in [0, m) (a wide plan:
+        (..., K, 2) hi and lo limbs), each device reducing
         its own B/D accumulators through the fused Barrett `mod_m` epilogue
         (`limbs.mod_u64`, DESIGN.md §2). Bit-identical to the single-device
         surface -- the reduction is per-row, sharding only changes the
@@ -280,7 +287,8 @@ class ShardedHasher:
         toks_p, lens_p, B = self._pad_rows(toks.reshape((-1, N)), lengths)
         args = (self.hasher, toks_p) if lens_p is None else (
             self.hasher, toks_p, lens_p)
-        return fn(*args)[:B].reshape(*batch_shape, self.spec.n_hashes)
+        K = self.spec.n_hashes
+        return fn(*args)[:B].reshape(*batch_shape, K, *(2,) * plan.wide)
 
     # -- host-convenience batched engine --------------------------------------
 
@@ -347,6 +355,33 @@ class ShardedHasher:
         return out
 
 
+def probe_owner_local(r, m: int, m_local: int, n_shards: int):
+    """Probe residues r in [0, m) -> (owner, local) int32 arrays:
+    owner = r // m_local, the shard that holds bit r, and local =
+    r - owner * m_local, its index inside that shard.
+
+    `r` is what `Hasher.probe_indices` gives: (...,) uint32 for m < 2^32,
+    (..., 2) uint32 (hi, lo) limbs for m >= 2^32. Pure 32-bit ops, no
+    division: the owner counts the shard edges d * m_local (0 < d <
+    n_shards, below m) that r reaches -- a two-limb compare against
+    literals -- and local is the low word of r - owner * m_local, which is
+    exact because the difference lies below m_local < 2^31."""
+    wide = m >= 1 << 32
+    hi, lo = (r[..., 0], r[..., 1]) if wide else (None, r)
+    owner = jnp.zeros(lo.shape, I32)
+    for d in range(1, n_shards):
+        edge = d * m_local
+        if edge >= m:  # no residue reaches it: the shards past are empty
+            break
+        e_hi, e_lo = np.uint32(edge >> 32), np.uint32(edge & 0xFFFFFFFF)
+        ge = lo >= e_lo
+        if wide:
+            ge = (hi > e_hi) | ((hi == e_hi) & ge)
+        owner = owner + ge.astype(I32)
+    local = lo - owner.astype(jnp.uint32) * np.uint32(m_local)
+    return owner, local.astype(I32)
+
+
 class DeviceShardedBloom:
     """k-probe Bloom filter whose bit array is range-partitioned over the
     mesh data axis: device d owns global bits [d*m_local, (d+1)*m_local).
@@ -358,26 +393,27 @@ class DeviceShardedBloom:
     *placement* is distributed. Storage is one device byte per bit (scatter/
     gather-native on the VPU; the packed-word layout of the host filter is a
     memory optimization this layer trades for collective-free scatters).
+    m may pass 2^32; each shard's m_local = ceil(m/D) must stay below 2^31,
+    the int32 local index.
 
-    Probe indices are computed IN-GRAPH: each device hashes its B/D rows
-    and reduces the (hi, lo) accumulator limbs mod m with the Barrett digit
-    reduction (`limbs.mod_u64`, exact for every 32-bit m -- DESIGN.md §2).
-    How the resulting (B/D, k) int32 global indices reach the devices that
+    Probes are computed IN-GRAPH: each device hashes its B/D rows and
+    reduces the (hi, lo) accumulator limbs mod m with the Barrett digit
+    reduction (`limbs.mod_u64`, exact for every m < 2^63, as two limbs past
+    2^32 -- DESIGN.md §2), then splits each residue into an (owner, local)
+    int32 pair (`probe_owner_local`). How the pairs reach the devices that
     OWN those bits is the `probe_transport` spec (`ProbeTransport`): the
-    default `"routed"` transport buckets them by owner (`g // m_local` --
-    the contiguous-range twin of the Lemire `(h*n)>>32` owner reduction,
-    over the padded bit domain) and exchanges only owned probes with one
-    `jax.lax.all_to_all`; `"all_gather"` replicates the full (B, k) matrix
-    (the PR 5 layout); `"host"` replays the legacy per-batch host
-    round-trip. Admission never leaves the device on either in-graph
-    transport.
+    default `"routed"` transport buckets them by owner and exchanges only
+    (local, row) pairs with one `jax.lax.all_to_all`; `"all_gather"`
+    replicates the full (B, k) pair matrix; `"host"` replays the legacy
+    per-batch host round-trip. A receiver sets and tests `local` as it is.
+    Admission never leaves the device on either in-graph transport.
 
     Collective layout (per-transport bytes table in DESIGN.md §7):
       add_batch             one launch, one collective, ZERO psums and ZERO
                             host syncs (each device scatters only its owned
                             range; foreign/sentinel probes drop)
       contains_batch        one launch, one collective + ONE psum (routed:
-                            miss counts scatter-added by global row id;
+                            miss counts reduced per global row id;
                             all_gather: per-device miss counts)
       check_and_add_batch   one fused launch, one collective + ONE psum
                             (verdicts against the pre-batch state, scatter)
@@ -409,7 +445,12 @@ class DeviceShardedBloom:
     factor by which the staged, copied and hashed bytes exceed the input.
     `stage_reuses`: staged calls written into the kept bucket buffer;
     `stage_allocs`: staged calls that had to allocate one (the first call,
-    and every change of bucket shape).
+    and every change of bucket shape). Counted per in-graph launch, from
+    shapes alone (no sync): `exchange_slots`, the slots of one device's
+    routed send buffer (D x capacity, routed launches only), and
+    `exchange_bytes`, the bytes of one device's send buffer on every
+    in-graph launch, replays included (8 a slot or pair: routed, D x
+    capacity (local, row) slots; all_gather, its (B/D) x k pairs).
 
     Host staging keeps ONE (Bp, Np) uint32 bucket buffer, Bp x Np x 4 bytes
     of host memory (268 MB for a 4096 x 16384 bucket), for the last bucket
@@ -417,11 +458,13 @@ class DeviceShardedBloom:
     it, and zeroes only what the last call wrote beyond the new lengths, so
     the staged bytes equal a fresh zero-padded stack. A call with another
     bucket shape drops it and allocates one of the new shape. The device
-    gets its own copy (`_stage`), so staged arrays held for an overflow
-    replay never see a later call's rewrite.
+    gets its own copy (`_stage`), rows split over the mesh as the launch
+    takes them, so staged arrays held for an overflow replay never see a
+    later call's rewrite.
 
     Spans (`jax.profiler.TraceAnnotation`, seen only under the profiler):
-    `bloom.check_and_add_batch` (metadata `call`, `items`, `width`) holds
+    `bloom.check_and_add_batch` (metadata `call`, `items`, `width`,
+    `shards`, `m_bits`) holds
     `bloom.stage`, `bloom.launch`, `bloom.readback` and, on a routed
     overflow, `bloom.overflow_replay`; `bloom.stage` holds, in order,
     `bloom.stage.stack` (the item lengths, and on a reuse the tokens
@@ -454,116 +497,121 @@ class DeviceShardedBloom:
         # identical (m, k) for identical inputs
         self.m = max(64, int(-n_items * math.log(fp_rate) / (math.log(2) ** 2)))
         self.k = max(1, int(self.m / n_items * math.log(2)))
-        if self.m >= 1 << 31:
-            raise ValueError(f"m={self.m} bits exceeds the int32 probe-index "
-                             "domain; shard the filter by keyspace first")
+        self.mesh = data_mesh() if mesh is None else mesh
+        D = mesh_axis_size(self.mesh, axis)
+        self.m_local = -(-self.m // D)
+        if self.m_local >= 1 << 31:
+            raise ValueError(
+                f"m={self.m} bits over {D} shard(s) is {self.m_local} bits a "
+                "shard, past the int32 local index (2^31): shard the filter "
+                "over more devices; packed bits (32 to an int32 word) would "
+                "lift the limit")
         # any engine family works: probes are `h % m` on the family's
         # 64-bit hash_batch surface on every path (host round-trip and the
         # fused in-graph mod_m epilogue agree per family by construction)
         self.sharded = ShardedHasher(Hasher.from_spec(HashSpec(
             family=family, n_hashes=self.k, out_bits=64,
-            variable_length=True, seed=seed)), mesh, axis)
-        self.mesh, self.axis = self.sharded.mesh, self.sharded.axis
+            variable_length=True, seed=seed)), self.mesh, axis)
+        self.axis = axis
         self.plan = limbs.ModPlan.for_modulus(self.m)
-        D = self.sharded.n_shards
-        self.m_local = -(-self.m // D)
         m_pad = self.m_local * D
-        sharding = NamedSharding(self.mesh, P(self.axis))
-        self._bits = jax.device_put(jnp.zeros(m_pad, U8), sharding)
+        self._rows = NamedSharding(self.mesh, P(axis))
+        self._bits = jax.jit(lambda: jnp.zeros(m_pad, U8),
+                             out_shardings=self._rows)()
         self._pending: list = []  # routed adds with unread overflow flags
         self.stats = {"overflow_fallbacks": 0, "calls": 0, "items": 0,
                       "real_tokens": 0, "staged_tokens": 0,
-                      "stage_reuses": 0, "stage_allocs": 0}
+                      "stage_reuses": 0, "stage_allocs": 0,
+                      "exchange_slots": 0, "exchange_bytes": 0}
         # the kept bucket buffer, its row views, how far each row was
         # written, and the device copy of its last contents (`_stage`)
         self._buf = self._buf_rows = self._buf_lens = self._buf_fence = None
 
-        m_local, ax, plan = self.m_local, self.axis, self.plan
+        m, m_local, ax, plan = self.m, self.m_local, axis, self.plan
         transport = self.transport
 
-        def _local(g):
-            """Global probe index -> (local index, owned mask) with foreign
-            probes clamped to the drop slot m_local (never wrapped: negative
-            scatter indices would alias the tail of the local range)."""
-            loc = g - jax.lax.axis_index(ax) * m_local
-            owned = (loc >= 0) & (loc < m_local)
-            return jnp.where(owned, loc, m_local), owned
+        def _pairs(hs, toks, lens, valid):
+            """(b, N) rows -> (b, k) owner and local int32 arrays: the
+            Barrett reduction of each device's own accumulators, split by
+            shard. Padding rows carry the owner sentinel D: owned by no
+            device, their probes drop from every scatter and read as
+            present (sliced off on host)."""
+            owner, local = probe_owner_local(
+                hs.probe_indices(toks, plan, lens), m, m_local, D)
+            return jnp.where(valid[:, None], owner, I32(D)), local
 
-        def _miss(bits, g):
-            loc, owned = _local(g)
+        def _local(p):
+            """(..., 2) (owner, local) pairs -> (local index, owned mask)
+            with other devices' probes sent to the drop slot m_local."""
+            owned = p[..., 0] == jax.lax.axis_index(ax)
+            return jnp.where(owned, p[..., 1], m_local), owned
+
+        def _miss(bits, p):
+            loc, owned = _local(p)
             probe = jnp.where(owned, bits[jnp.clip(loc, 0, m_local - 1)],
                               U8(1))
             return jax.lax.psum(
                 jnp.sum((probe == 0).astype(I32), axis=1), ax)
 
-        def _probes_in_graph(hs, toks, lens, valid):
-            """(b_local, N) rows -> (B, k) int32 GLOBAL probe indices: the
-            Barrett digit reduction of each device's own accumulators, then
-            one all_gather of the int32 indices along the data axis (the
-            device-to-device twin of the old host round-trip). Padding rows
-            carry the sentinel -1: owned by no device, so their probes drop
-            from every scatter and read as present (sliced off on host)."""
-            g = hs.probe_indices(toks, plan, lens).astype(I32)
-            g = jnp.where(valid[:, None], g, I32(-1))
-            return jax.lax.all_gather(g, ax, axis=0, tiled=True)
+        def _pairs_in_graph(hs, toks, lens, valid):
+            """(b_local, N) rows -> (B, k, 2) int32 (owner, local) pairs of
+            every row: one all_gather of each device's pairs along the data
+            axis (the device-to-device twin of the old host round-trip)."""
+            return jax.lax.all_gather(
+                jnp.stack(_pairs(hs, toks, lens, valid), axis=-1), ax,
+                axis=0, tiled=True)
 
-        def add_body(bits, g):
-            loc, _ = _local(g)
+        def add_body(bits, p):
+            loc, _ = _local(p)
             return bits.at[loc.ravel()].set(U8(1), mode="drop")
 
-        def contains_body(bits, g):
-            return _miss(bits, g) == 0
+        def contains_body(bits, p):
+            return _miss(bits, p) == 0
 
-        def admit_body(bits, g):
-            present = _miss(bits, g) == 0
-            loc, _ = _local(g)
+        def admit_body(bits, p):
+            present = _miss(bits, p) == 0
+            loc, _ = _local(p)
             return bits.at[loc.ravel()].set(U8(1), mode="drop"), ~present
 
         def add_body_dev(bits, hs, toks, lens, valid):
-            return add_body(bits, _probes_in_graph(hs, toks, lens, valid))
+            return add_body(bits, _pairs_in_graph(hs, toks, lens, valid))
 
         def contains_body_dev(bits, hs, toks, lens, valid):
-            return contains_body(bits, _probes_in_graph(hs, toks, lens, valid))
+            return contains_body(bits, _pairs_in_graph(hs, toks, lens, valid))
 
         def admit_body_dev(bits, hs, toks, lens, valid):
-            return admit_body(bits, _probes_in_graph(hs, toks, lens, valid))
+            return admit_body(bits, _pairs_in_graph(hs, toks, lens, valid))
 
         # -- routed transport: owner-bucketed all_to_all probe exchange ----
 
         def _route(hs, toks, lens, valid):
             """Bucket this device's (b, k) probes by owning device and
-            exchange only owned probes: (recv_g, recv_row, overflow, b).
+            exchange only owned probes: (recv_local, recv_row, overflow, b).
 
-            Each probe g is owned by device `g // m_local` -- over the
-            padded bit domain m_pad = m_local*D this IS the Lemire
-            multiply-shift `(g*D) >> log2-range` owner reduction that
-            `owner_shards` uses, specialized to the contiguous range map.
             Probes pack into a static (D, cap, 2) int32 send buffer of
-            (global index, sender-local row) pairs. Compaction is
-            SCATTER-FREE (CPU scatters serialize; this pack used to cost
-            as much as the exchange): a transposed per-destination running
-            count (cumsum along the contiguous axis), then a vectorized
-            binary search -- bucket d's j-th slot holds the first flat
-            probe index whose running count reaches j+1 -- so slots fill
-            first-fit in flat-index order and every buffer builds from
-            gathers alone. The -1 sentinel fills unused capacity and
-            carries invalid rows (their local-row word is the b sentinel);
-            sentinel probes route to the out-of-range bucket D (HIGH,
-            never negative: a negative bucket would wrap and alias real
-            buckets). One tiled `all_to_all` then swaps bucket d to device
-            d -- first-fit order guarantees each received bucket's rows
-            are non-decreasing with the sentinel tail last, which is what
-            lets `_miss_rt` reduce per-row misses without a scatter.
-            Probes beyond `cap` never pack and raise the per-device
-            overflow flag -- the host-side settle path repairs via
-            all_gather."""
-            g = hs.probe_indices(toks, plan, lens).astype(I32)
-            g = jnp.where(valid[:, None], g, I32(-1))
-            b, k = g.shape
+            (local index, sender-local row) pairs, bucket d holding the
+            probes device d owns. Compaction is SCATTER-FREE (CPU scatters
+            serialize; this pack used to cost as much as the exchange): a
+            transposed per-destination running count (cumsum along the
+            contiguous axis), then a vectorized binary search -- bucket
+            d's j-th slot holds the first flat probe index whose running
+            count reaches j+1 -- so slots fill first-fit in flat-index
+            order and every buffer builds from gathers alone. Unused
+            capacity carries the drop slot m_local as its local index and
+            the b sentinel as its row; padding rows' probes carry the owner
+            sentinel D, the out-of-range bucket (HIGH, never negative: a
+            negative bucket would wrap and alias real buckets). One tiled
+            `all_to_all` then swaps bucket d to device d -- first-fit
+            order guarantees each received bucket's rows are
+            non-decreasing with the sentinel tail last, which is what lets
+            `_miss_rt` reduce per-row misses without a scatter. Probes
+            beyond `cap` never pack and raise the per-device overflow flag
+            -- the host-side settle path repairs via all_gather."""
+            owner, local = _pairs(hs, toks, lens, valid)
+            b, k = owner.shape
             n_probes = b * k
             cap = transport.capacity(n_probes, D)
-            gf = g.reshape(n_probes)
-            dest = jnp.where(gf >= 0, gf // I32(m_local), I32(D))
+            dest = owner.reshape(n_probes)
             onehot = dest[None, :] == jnp.arange(D, dtype=I32)[:, None]
             pos = jnp.cumsum(onehot.astype(I32), axis=1)  # (D, n) running
             counts = pos[:, -1]
@@ -571,23 +619,21 @@ class DeviceShardedBloom:
             si = jax.vmap(lambda c: jnp.searchsorted(
                 c, jnp.arange(cap, dtype=I32) + 1))(pos)
             ok = jnp.arange(cap, dtype=I32)[None, :] < counts[:, None]
-            sg = jnp.where(ok, gf[jnp.clip(si, 0, n_probes - 1)], I32(-1))
+            sl = jnp.where(ok, local.reshape(n_probes)[
+                jnp.clip(si, 0, n_probes - 1)], I32(m_local))
             sr = jnp.where(ok, si.astype(I32) // I32(k), I32(b))
             recv = jax.lax.all_to_all(
-                jnp.stack([sg, sr], axis=-1), ax,
+                jnp.stack([sl, sr], axis=-1), ax,
                 split_axis=0, concat_axis=0, tiled=True)
             return recv[..., 0], recv[..., 1], overflow, b
 
-        def _scatter_rt(bits, recv_g):
-            """Set every received owned bit; sentinel (-1) and any stray
-            foreign index clamp to the drop slot m_local (mode="drop")."""
-            loc = recv_g - jax.lax.axis_index(ax) * m_local
-            ok = (recv_g >= 0) & (loc >= 0) & (loc < m_local)
-            return bits.at[jnp.where(ok, loc, m_local).ravel()].set(
-                U8(1), mode="drop")
+        def _scatter_rt(bits, recv_local):
+            """Set every received bit; the unused slots' drop index m_local
+            falls outside the shard (mode="drop")."""
+            return bits.at[recv_local.ravel()].set(U8(1), mode="drop")
 
-        def _miss_rt(bits, recv_g, recv_row, b):
-            """(Bp,) global miss counts: test received owned probes locally,
+        def _miss_rt(bits, recv_local, recv_row, b):
+            """(Bp,) global miss counts: test received probes locally,
             total per-row misses, ONE psum across devices. A row's total
             miss count is 0 iff all k of its global bits are set --
             identical verdict to the all_gather membership test even when
@@ -601,10 +647,10 @@ class DeviceShardedBloom:
             into pure gathers, and block s's (b,) counts land at global
             rows [s*b, (s+1)*b) by plain reshape (device s only ever sends
             its own rows)."""
-            loc = recv_g - jax.lax.axis_index(ax) * m_local
-            ok = (recv_g >= 0) & (loc >= 0) & (loc < m_local)
-            probe = jnp.where(ok, bits[jnp.clip(loc, 0, m_local - 1)], U8(1))
-            miss = (ok & (probe == 0)).astype(I32)  # (D, cap)
+            probe = jnp.where(recv_local < m_local,
+                              bits[jnp.minimum(recv_local, m_local - 1)],
+                              U8(1))
+            miss = (probe == 0).astype(I32)  # (D, cap)
             cs = jnp.concatenate(
                 [jnp.zeros((D, 1), I32), jnp.cumsum(miss, axis=1)], axis=1)
             edges = jax.vmap(lambda r: jnp.searchsorted(
@@ -614,18 +660,18 @@ class DeviceShardedBloom:
             return jax.lax.psum(counts.reshape(b * D), ax)
 
         def add_body_rt(bits, hs, toks, lens, valid):
-            recv_g, _, overflow, _ = _route(hs, toks, lens, valid)
-            return _scatter_rt(bits, recv_g), overflow[None]
+            recv_l, _, overflow, _ = _route(hs, toks, lens, valid)
+            return _scatter_rt(bits, recv_l), overflow[None]
 
         def contains_body_rt(bits, hs, toks, lens, valid):
-            recv_g, recv_row, overflow, b = _route(hs, toks, lens, valid)
-            present = _miss_rt(bits, recv_g, recv_row, b) == 0
+            recv_l, recv_row, overflow, b = _route(hs, toks, lens, valid)
+            present = _miss_rt(bits, recv_l, recv_row, b) == 0
             return present, overflow[None]
 
         def admit_body_rt(bits, hs, toks, lens, valid):
-            recv_g, recv_row, overflow, b = _route(hs, toks, lens, valid)
-            present = _miss_rt(bits, recv_g, recv_row, b) == 0
-            return _scatter_rt(bits, recv_g), ~present, overflow[None]
+            recv_l, recv_row, overflow, b = _route(hs, toks, lens, valid)
+            present = _miss_rt(bits, recv_l, recv_row, b) == 0
+            return _scatter_rt(bits, recv_l), ~present, overflow[None]
 
         sm = lambda body, out_specs: jax.jit(jax.shard_map(  # noqa: E731
             body, mesh=self.mesh, in_specs=(P(self.axis), P()),
@@ -663,7 +709,8 @@ class DeviceShardedBloom:
 
     @property
     def bits(self) -> jnp.ndarray:
-        """The (m_local * D,) uint8 global bit array (device-sharded). A
+        """The (m_local * D,) uint8 global bit array (device-sharded; bits
+        at and past m stay 0). A
         read settles any pending routed adds first, so observers always see
         repaired, `BloomFilter`-identical state."""
         self._settle()
@@ -686,6 +733,7 @@ class DeviceShardedBloom:
         self.stats["overflow_fallbacks"] += len(replay)
         with _span("bloom.overflow_replay"):
             for toks, lens, valid in replay:
+                self._count_exchange(toks.shape[0], routed=False)
                 self._bits = self._add_dev(
                     self._bits, self.sharded.hasher, toks, lens, valid)
         if self.transport.on_overflow == "error":
@@ -697,20 +745,36 @@ class DeviceShardedBloom:
                 "or use probe_transport='all_gather'")
 
     def _probes(self, items) -> np.ndarray:
-        """LEGACY host round-trip path (`probe_transport="host"`): (B, k) int32
-        GLOBAL probe indices -- the full 64-bit accumulators mod m, exactly
-        the single-device `BloomFilter` formula, hashed B/D rows per device
-        then reduced with numpy's `%` on host. Bit-identical to the in-graph
-        Barrett reduction; kept as the A/B reference and bench baseline."""
-        h = self.sharded.hash_batch(items)  # (B, k) uint64
-        return (h % np.uint64(self.m)).astype(np.int32)
+        """LEGACY host round-trip path (`probe_transport="host"`): (B, k, 2)
+        int32 (owner, local) probe pairs -- the full 64-bit accumulators mod
+        m, exactly the single-device `BloomFilter` formula, hashed B/D rows
+        per device then reduced with numpy's `%` and split by shard on the
+        host. Bit-identical to the in-graph Barrett reduction and
+        `probe_owner_local`; kept as the A/B reference and bench baseline."""
+        r = (self.sharded.hash_batch(items) % np.uint64(self.m)).astype(
+            np.int64)  # (B, k)
+        owner = r // self.m_local
+        return np.stack([owner, r - owner * self.m_local],
+                        axis=-1).astype(np.int32)
+
+    def _count_exchange(self, rows: int, routed: bool) -> None:
+        """Add one in-graph launch over `rows` staged rows to the exchange
+        counters (class docstring), from shapes alone."""
+        D = self.n_shards
+        n = rows // D * self.k  # one device's probes
+        if routed:
+            slots = D * self.transport.capacity(n, D)
+            self.stats["exchange_slots"] += slots
+            self.stats["exchange_bytes"] += slots * 8
+        else:
+            self.stats["exchange_bytes"] += n * 8
 
     def _stage(self, items):
         """Stage host items for the in-graph path: (Bp, Np) uint32 tokens,
         (Bp,) int32 lengths, (Bp,) bool row-valid mask, true batch size B.
         Shapes bucket via `_bucket_shape` (the same bounded-trace policy as
         `ShardedHasher.hash_batch`); padding rows are invalid -- their
-        probes become the -1 sentinel in-graph. Tokens go once into the
+        probes carry the owner sentinel D in-graph. Tokens go once into the
         kept bucket buffer (class docstring): row i holds item i in columns
         [0, len_i) and zeros elsewhere, as a fresh zero-padded stack would."""
         with _span("bloom.stage"):
@@ -761,10 +825,12 @@ class DeviceShardedBloom:
                 self.sharded.ensure(Np)
                 # a runtime may zero-copy host memory (the CPU client does,
                 # for aligned arrays): the device-side copy is what no later
-                # rewrite of the buffer can reach
-                toks = jnp.copy(jax.device_put(self._buf))
+                # rewrite of the buffer can reach. Rows go straight to the
+                # device that hashes them.
+                toks = jnp.copy(jax.device_put(self._buf, self._rows))
                 self._buf_fence = toks
-                return toks, jnp.asarray(lens_p), jnp.asarray(valid), B
+                lens_d, valid_d = jax.device_put((lens_p, valid), self._rows)
+                return toks, lens_d, valid_d, B
 
     def _fill(self, src) -> None:
         """Write item tokens into the kept buffer, casting in place: a
@@ -799,6 +865,7 @@ class DeviceShardedBloom:
                 self._bits, jnp.asarray(self._probes(items)))
             return
         toks, lens, valid, _ = self._stage(items)
+        self._count_exchange(toks.shape[0], routed=kind == "routed")
         if kind == "all_gather":
             self._bits = self._add_dev(
                 self._bits, self.sharded.hasher, toks, lens, valid)
@@ -821,6 +888,7 @@ class DeviceShardedBloom:
                 self._contains(self._bits, jnp.asarray(self._probes(items))))
         self._settle()
         toks, lens, valid, B = self._stage(items)
+        self._count_exchange(toks.shape[0], routed=kind == "routed")
         if kind == "all_gather":
             return np.asarray(self._contains_dev(
                 self._bits, self.sharded.hasher, toks, lens, valid))[:B]
@@ -828,6 +896,7 @@ class DeviceShardedBloom:
             self._bits, self.sharded.hasher, toks, lens, valid)
         if bool(np.asarray(flag).any()):
             self._overflowed("contains_batch")
+            self._count_exchange(toks.shape[0], routed=False)
             verdict = self._contains_dev(
                 self._bits, self.sharded.hasher, toks, lens, valid)
         return np.asarray(verdict)[:B]
@@ -849,7 +918,9 @@ class DeviceShardedBloom:
             self._settle()
             toks, lens, valid, B = self._stage(items)
             span.set_metadata(call=self.stats["calls"], items=B,
-                              width=toks.shape[1])
+                              width=toks.shape[1], shards=self.n_shards,
+                              m_bits=self.m)
+            self._count_exchange(toks.shape[0], routed=kind == "routed")
             args = (self.sharded.hasher, toks, lens, valid)
             if kind == "all_gather":
                 with _span("bloom.launch"):
@@ -867,6 +938,7 @@ class DeviceShardedBloom:
                 # and rerun against the untouched pre-call bits via all_gather
                 with _span("bloom.overflow_replay"):
                     self._overflowed("check_and_add_batch")
+                    self._count_exchange(toks.shape[0], routed=False)
                     new_bits, admitted = self._admit_dev(self._bits, *args)
                     admitted = np.asarray(admitted)[:B]
             self._bits = new_bits

@@ -184,7 +184,8 @@ class Hasher:
         tokens -> (..., K, 2) epilogue slots. Without mod_m the slots are
         the (hi, lo) accumulator limbs; with a `limbs.ModPlan` the backend
         fuses the Barrett reduction into its epilogue (DESIGN.md §2) and
-        slot 0 is the probe index, slot 1 the finished 32-bit hash."""
+        slot 0 is the probe index, slot 1 the finished 32-bit hash (a wide
+        plan: the probe index's hi and lo limbs)."""
         spec = self.spec
         toks = jnp.asarray(tokens)
         batch_shape = toks.shape[:-1]
@@ -286,10 +287,11 @@ class Hasher:
         return hi.astype(I32)
 
     def probe_indices(self, tokens, plan, lengths=None):
-        """(..., N) tokens -> (..., K) uint32 Bloom probe indices in [0, m):
-        the family's full 64-bit surface mod `plan.m` -- the exact single-
-        device `BloomFilter` formula (`h % m` on the uint64 hash_batch
-        output, for every engine family). The
+        """(..., N) tokens -> (..., K) uint32 Bloom probe indices in [0, m)
+        -- or, for a wide plan (m >= 2^32), (..., K, 2) uint32 (hi, lo)
+        limbs of each index: the family's full 64-bit surface mod
+        `plan.m` -- the exact single-device `BloomFilter` formula (`h % m`
+        on the uint64 hash_batch output, for every engine family). The
         Barrett digit reduction (`limbs.mod_u64`) runs FUSED in the
         backend's epilogue (the kernel `mod_m=` path: the accumulator never
         leaves registers before reducing), so this is pure JAX
@@ -304,7 +306,8 @@ class Hasher:
                              "reduction consumes the full accumulator)")
         if not isinstance(plan, limbs.ModPlan):
             plan = limbs.ModPlan.for_modulus(plan)
-        return self._hash_limbs(tokens, lengths, mod_m=plan)[..., 0]
+        out = self._hash_limbs(tokens, lengths, mod_m=plan)
+        return out if plan.wide else out[..., 0]
 
     # -- host-convenience batched engine -------------------------------------
 

@@ -15,7 +15,8 @@ reduction all fused into the same epilogue slots as the integer engine:
   "hi == the 32-bit hash" convention holds unchanged;
 - with `mod_m=` (a static `limbs.ModPlan`): slot 0 = `h64 mod m` (the
   Bloom probe index -- identical to the host `h % m` formula on the
-  uint64 surface), slot 1 = the finished 32-bit hash.
+  uint64 surface), slot 1 = the finished 32-bit hash; a wide plan
+  (m >= 2^32) puts the residue's hi and lo limbs in slots 0 and 1.
 
 TPU has no CLMUL instruction (DESIGN.md §2): the 32x32 -> 63-bit carry-
 less product is decomposed into 32 SHIFTED PARTIAL-PRODUCT PLANES
@@ -143,6 +144,10 @@ def _gf_multihash_kernel(tok_ref, kl_ref, len_ref, m1_ref, out_ref,
             if mod_m is None:
                 out_ref[:, k, 0] = h32
                 out_ref[:, k, 1] = acc_hi
+            elif mod_m.wide:
+                r_hi, r_lo = limbs.mod_u64((h32, acc_hi), mod_m)
+                out_ref[:, k, 0] = r_hi
+                out_ref[:, k, 1] = r_lo
             else:
                 out_ref[:, k, 0] = limbs.mod_u64((h32, acc_hi), mod_m)
                 out_ref[:, k, 1] = h32
@@ -176,7 +181,7 @@ def gf_multihash_blocks(
 
     mod_m (a `limbs.ModPlan`, static): fuse the probe reduction into the
     epilogue -- slot [..., 0] becomes h64 mod m, slot [..., 1] the
-    finished 32-bit hash.
+    finished 32-bit hash (a wide plan: the residue's hi and lo limbs).
     """
     B, N = tokens.shape
     K = key_lo.shape[0]

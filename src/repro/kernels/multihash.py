@@ -123,8 +123,10 @@ def _multihash_kernel(tok_ref, kh_ref, kl_ref, len_ref, m1_ref, out_ref,
         # fused finish: + m1, then >>32 == "hash is the hi limb" (slot 0).
         # With mod_m the Bloom probe reduction also fuses here: slot 0 is
         # the full 64-bit accumulator mod m (limbs.mod_u64, DESIGN.md §2),
-        # slot 1 keeps the finished 32-bit hash -- the ModPlan reciprocal
-        # limbs are numpy-scalar literals, so the kernel stays constant-free.
+        # slot 1 keeps the finished 32-bit hash; a wide plan (m >= 2^32)
+        # writes the residue's (hi, lo) limbs into slots 0 and 1 -- the
+        # ModPlan reciprocal limbs are numpy-scalar literals, so the kernel
+        # stays constant-free.
         for k in range(n_hashes):
             m1h = jnp.broadcast_to(m1_ref[k, 0], (bb,))
             m1l = jnp.broadcast_to(m1_ref[k, 1], (bb,))
@@ -133,6 +135,10 @@ def _multihash_kernel(tok_ref, kh_ref, kl_ref, len_ref, m1_ref, out_ref,
             if mod_m is None:
                 out_ref[:, k, 0] = hi
                 out_ref[:, k, 1] = lo
+            elif mod_m.wide:
+                r_hi, r_lo = limbs.mod_u64((hi, lo), mod_m)
+                out_ref[:, k, 0] = r_hi
+                out_ref[:, k, 1] = r_lo
             else:
                 out_ref[:, k, 0] = limbs.mod_u64((hi, lo), mod_m)
                 out_ref[:, k, 1] = hi
@@ -164,7 +170,8 @@ def multihash_blocks(
 
     mod_m (a `limbs.ModPlan`, static): fuse the Bloom probe reduction into
     the epilogue -- slot [..., 0] becomes the full 64-bit accumulator mod m,
-    slot [..., 1] the finished 32-bit hash.
+    slot [..., 1] the finished 32-bit hash; under a wide plan (m >= 2^32)
+    slots [..., 0] and [..., 1] are the residue's hi and lo limbs.
     """
     B, N = tokens.shape
     K = key_hi.shape[0]

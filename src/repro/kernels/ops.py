@@ -70,7 +70,8 @@ def multihash(tokens, key_hi, key_lo, lens, m1, *, family="multilinear",
     backend: 'pallas' (TPU), 'interpret' (kernel body on CPU), 'jnp' (fused
     oracle -- the fast CPU production path).
     mod_m (a `limbs.ModPlan`, static): fuse the probe reduction into the
-    epilogue -- output slot 0 = accumulator mod m, slot 1 = 32-bit hash.
+    epilogue -- output slot 0 = accumulator mod m, slot 1 = 32-bit hash
+    (a wide plan, m >= 2^32: the residue's hi and lo limbs).
     """
     _LAUNCHES[0] += 1
     return _multihash_jit(

@@ -35,7 +35,8 @@ def multihash_ref(tokens, key_hi, key_lo, lens, m1, family="multilinear",
 
     Same semantics as `multihash.multihash_blocks` (length-code masking,
     m1 add, hash32 in slot 0; with mod_m the slot-0 probe reduction and
-    slot-1 hash32) with the K loop unrolled over limb-jnp ops.
+    slot-1 hash32, or a wide plan's residue limbs in both) with the K loop
+    unrolled over limb-jnp ops.
     """
     from .multihash import _mask_tile
 
@@ -64,7 +65,8 @@ def multihash_ref(tokens, key_hi, key_lo, lens, m1, family="multilinear",
             (jnp.broadcast_to(m1[k, 0], hi.shape),
              jnp.broadcast_to(m1[k, 1], lo.shape)))
         if mod_m is not None:
-            outs.append(jnp.stack([limbs.mod_u64((hi, lo), mod_m), hi],
+            r = limbs.mod_u64((hi, lo), mod_m)
+            outs.append(jnp.stack(list(r) if mod_m.wide else [r, hi],
                                   axis=-1))
         else:
             outs.append(jnp.stack([hi, lo], axis=-1))
@@ -77,7 +79,8 @@ def gf_multihash_ref(tokens, key_lo, lens, m1, family="gf_multilinear",
 
     Same semantics as `gf_multihash.gf_multihash_blocks` (length-code
     masking, m1 xor, Barrett, hash32 in slot 0 / accumulator hi limb in
-    slot 1; with mod_m the slot-0 probe reduction and slot-1 hash32) with
+    slot 1; with mod_m the slot-0 probe reduction and slot-1 hash32, or a
+    wide plan's residue limbs in both) with
     the K loop unrolled over the shared partial-product-plane clmul.
     """
     from .gf_multihash import _clmul_tile, _xor_reduce_tile
@@ -104,7 +107,8 @@ def gf_multihash_ref(tokens, key_lo, lens, m1, family="gf_multilinear",
             jnp.uint32)
         h32 = gf_core.barrett_reduce(acc_hi, acc_lo)
         if mod_m is not None:
-            outs.append(jnp.stack([limbs.mod_u64((h32, acc_hi), mod_m), h32],
+            r = limbs.mod_u64((h32, acc_hi), mod_m)
+            outs.append(jnp.stack(list(r) if mod_m.wide else [r, h32],
                                   axis=-1))
         else:
             outs.append(jnp.stack([h32, acc_hi], axis=-1))

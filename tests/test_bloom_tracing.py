@@ -76,16 +76,21 @@ def _within(inner, outer) -> bool:
 @pytest.mark.parametrize("transport", ["routed", "all_gather"])
 def test_counters_are_exact_on_every_in_graph_path(transport):
     bloom = _bloom(transport)
+    k, routed = bloom.k, transport == "routed"
     assert bloom.stats == {"overflow_fallbacks": 0, "calls": 0, "items": 0,
                            "real_tokens": 0, "staged_tokens": 0,
-                           "stage_reuses": 0, "stage_allocs": 0}
+                           "stage_reuses": 0, "stage_allocs": 0,
+                           "exchange_slots": 0, "exchange_bytes": 0}
     # three ragged batches and a dense one, all in the (8, 16) bucket
     a, b, c = [3, 1, 13, 7, 2], [5, 9, 4, 1, 1, 2], [16, 2, 6, 6, 1, 11, 3]
     bloom.check_and_add_batch(_ragged(a))
+    # one device: a routed bucket's capacity clamps to its 8 x k probes
     assert bloom.stats == {"overflow_fallbacks": 0, "calls": 1, "items": 5,
                            "real_tokens": sum(a),
                            "staged_tokens": _staged(a),
-                           "stage_reuses": 0, "stage_allocs": 1}
+                           "stage_reuses": 0, "stage_allocs": 1,
+                           "exchange_slots": 8 * k * routed,
+                           "exchange_bytes": 8 * k * 8}
     assert _staged(a) == 8 * 16  # 5 rows -> 8, width 13 -> 16
     bloom.add_batch(_ragged(b))
     bloom.contains_batch(_ragged(c))
@@ -108,6 +113,9 @@ def test_counters_are_exact_on_every_in_graph_path(transport):
     bloom.add_batch(_ragged([12, 10]))           # (2, 16): reused
     assert (bloom.stats["stage_reuses"], bloom.stats["stage_allocs"]) == (4, 5)
     assert bloom.stats["calls"] == 9
+    rows = 4 * 8 + 2 + 1 + 2 + 2 + 2  # staged rows of the nine calls
+    assert bloom.stats["exchange_slots"] == rows * k * routed
+    assert bloom.stats["exchange_bytes"] == rows * k * 8
 
 
 def test_host_transport_stages_nothing():
@@ -115,7 +123,8 @@ def test_host_transport_stages_nothing():
     bloom.check_and_add_batch(_ragged([4, 2]))
     assert bloom.stats == {"overflow_fallbacks": 0, "calls": 0, "items": 0,
                            "real_tokens": 0, "staged_tokens": 0,
-                           "stage_reuses": 0, "stage_allocs": 0}
+                           "stage_reuses": 0, "stage_allocs": 0,
+                           "exchange_slots": 0, "exchange_bytes": 0}
 
 
 def test_admission_call_writes_every_span_nested(tmp_path):
@@ -126,8 +135,8 @@ def test_admission_call_writes_every_span_nested(tmp_path):
         _ragged(lengths)) for _ in range(2)])
     outer = [e for e in spans if e[0] == "bloom.check_and_add_batch"]
     assert [e[3] for e in outer] == [
-        {"call": 2, "items": 5, "width": 16},
-        {"call": 3, "items": 5, "width": 16}]
+        {"call": 2, "items": 5, "width": 16, "shards": 1, "m_bits": bloom.m},
+        {"call": 3, "items": 5, "width": 16, "shards": 1, "m_bits": bloom.m}]
     for call in outer:
         inside = [e for e in spans if _within(e, call)]
         assert sorted(e[0] for e in inside) == sorted(NESTING)
@@ -179,3 +188,9 @@ def test_routed_overflow_writes_the_replay_span(tmp_path):
     readback = next(e for e in spans if e[0] == "bloom.readback")
     assert readback[2] <= replays[1][1]
     assert bloom.stats["overflow_fallbacks"] == 4
+    # four routed launches, each of one slot a bucket (the capacity's
+    # floor), and each replayed through all_gather: (3 or 4 rows -> 4) x k
+    # pairs of 8 bytes
+    k = bloom.k
+    assert bloom.stats["exchange_slots"] == 4
+    assert bloom.stats["exchange_bytes"] == 4 * 8 + 4 * (4 * k * 8)

@@ -90,9 +90,11 @@ def test_mw_mod_multiword_vs_python_int(m):
 
 
 def test_mod_plan_validation_and_hashability():
-    for bad in (0, -1, 1 << 32, (1 << 32) + 5):
+    for bad in (0, -1, 1 << 63, 1 << 64):
         with pytest.raises(ValueError):
             ModPlan.for_modulus(bad)
+    # the numpy twin stays the 32-bit reduction
+    for bad in (0, -1, 1 << 32, (1 << 32) + 5):
         with pytest.raises(ValueError):
             hostref.mod_u64_np(np.uint64(1), bad)
     # frozen + hashable: usable as a jit static argument / dict key
@@ -188,3 +190,99 @@ def test_hasher_probe_indices_matches_bloom_formula():
     with pytest.raises(ValueError, match="out_bits=64"):
         Hasher.from_spec(HashSpec(n_hashes=1, seed=1)).probe_indices(
             jnp.asarray(toks), 97)
+
+
+# ---------------------------------------------------------------------------
+# wide plans: m >= 2^32, the residue as (hi, lo) limbs
+# ---------------------------------------------------------------------------
+
+WIDE_M = [2**32 - 1, 2**32, 2**32 + 1, 7_188_793_783, 2**40 + 15, 2**62 + 3]
+
+
+def _join(r, plan):
+    """mod_u64's result as python ints: one uint32, or (hi, lo) limbs."""
+    if not plan.wide:
+        return [int(v) for v in np.asarray(r)]
+    hi, lo = (np.asarray(x).astype(np.uint64) for x in r)
+    return [int(v) for v in (hi << np.uint64(32)) | lo]
+
+
+@pytest.mark.parametrize("m", WIDE_M)
+def test_mod_u64_past_2_32_vs_python_int(m):
+    """Seeded random 64-bit x and the edges, on both sides of 2^32: below
+    it one uint32 residue, from 2^32 on two limbs, every one exact."""
+    h = np.concatenate([_random_h(2048), EDGE_H,
+                        np.array([m - 1, m, m + 1, 2 * m - 1, 2 * m,
+                                  (2**64 - 1) // m * m,
+                                  (2**64 - 1) // m * m - 1], np.uint64)])
+    plan = ModPlan.for_modulus(m)
+    assert plan.wide == (m >= 2**32)
+    got = _join(jax.jit(lambda a, b: limbs.mod_u64((a, b), plan))(*_split(h)),
+                plan)
+    assert got == [int(x) % m for x in h]
+
+
+@pytest.mark.parametrize("m", WIDE_M)
+def test_mod_plan_reciprocal_width(m):
+    """M = floor(2^N/m) + 1 with N = 96 below 2^32 and 128 from it on,
+    three limbs either way; powers of two carry none."""
+    p = ModPlan.for_modulus(m)
+    mu = p.mu0 | (p.mu1 << 32) | (p.mu2 << 64)
+    if p.is_pow2:
+        assert mu == 0
+        return
+    assert mu == (1 << (128 if p.wide else 96)) // m + 1
+    assert max(p.mu0, p.mu1, p.mu2) < 2**32
+
+
+def test_narrow_plans_keep_one_uint32_residue():
+    """Below 2^32 nothing changes: one uint32 array out of `mod_u64` and
+    of `Hasher.probe_indices`, and a plan that is not wide."""
+    from repro.hash import Hasher, HashSpec
+
+    for m in (97, 1024, 2**31 + 1, 2**32 - 1):
+        plan = ModPlan.for_modulus(m)
+        assert not plan.wide
+        r = limbs.mod_u64(_split(_random_h(8)), plan)
+        assert r.dtype == jnp.uint32 and r.shape == (8,)
+    h = Hasher.from_spec(HashSpec(n_hashes=3, out_bits=64, seed=3),
+                         max_len=8)
+    toks = jnp.ones((5, 8), jnp.uint32)
+    narrow = h.probe_indices(toks, 2**32 - 1)
+    assert narrow.shape == (5, 3) and narrow.dtype == jnp.uint32
+    assert h.probe_indices(toks, 2**32 + 15).shape == (5, 3, 2)
+    with pytest.raises(ValueError, match="below 2"):
+        limbs.mw_mod(_split(_random_h(2)), ModPlan.for_modulus(2**32 + 15))
+
+
+@pytest.mark.parametrize("family", ["multilinear", "multilinear_hm",
+                                    "gf_multilinear", "gf_multilinear_hm"])
+@pytest.mark.parametrize("m", [2**32 + 15, 7_188_793_783])
+def test_kernel_mod_m_epilogue_past_2_32(family, m):
+    """Both fused kernels' `mod_m` epilogues (integer and GF, in
+    interpret mode) and the jnp oracle write h64 mod m into the two output
+    slots as (hi, lo), equal to `hash_batch(out_bits=64) % m`."""
+    from repro.hash import Hasher, HashPlan, HashSpec
+
+    h = Hasher.from_spec(HashSpec(family=family, n_hashes=3, out_bits=64,
+                                  variable_length=True, seed=0x3D4),
+                         max_len=40)
+    rows = [RNG.integers(0, 2**32, size=n, dtype=np.uint64).astype(np.uint32)
+            for n in (1, 7, 16, 33, 3, 12)]
+    acc = h.hash_batch(rows, backend="host")  # (6, 3) uint64
+    want = acc % np.uint64(m)
+    toks = np.zeros((6, 40), np.uint32)
+    for i, row in enumerate(rows):
+        toks[i, :len(row)] = row
+    lens = jnp.asarray([len(r) for r in rows], jnp.int32)
+    for plan in (HashPlan(backend="jnp"),
+                 HashPlan(backend="interpret", block_b=8, block_n=16)):
+        out = np.asarray(h.with_plan(plan).probe_indices(
+            jnp.asarray(toks), m, lens)).astype(np.uint64)
+        assert out.shape == (6, 3, 2)
+        got = (out[..., 0] << np.uint64(32)) | out[..., 1]
+        np.testing.assert_array_equal(got, want, plan.backend)
+    # residues past 2^32 (about 40% at 7.19e9; at 2^32 + 15 one in 2^28),
+    # and a reduction that is no truncation to 32 bits
+    assert (want >= 2**32).any() or m < 2**33
+    assert (want != acc % np.uint64(2**32)).any()

@@ -94,7 +94,7 @@ def test_transports_produce_identical_bit_state():
 
 
 def test_routed_sentinel_rows_owned_by_no_device():
-    """Staged padding rows carry the -1 probe sentinel: they must light NO
+    """Staged padding rows carry the owner sentinel D: they must light NO
     bits through the routed exchange (an all-invalid add leaves the filter
     empty and raises no overflow) and read back as 'present' in the raw
     verdict vector (sliced off by the host wrapper)."""

@@ -16,6 +16,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.core.limbs import ModPlan
 from repro.hash import Hasher, HashPlan, HashSpec, ShardedHasher
+from repro.hash.distributed import probe_owner_local
 from repro.kernels import gf_multihash as gfmh
 from repro.kernels import multihash as mhk
 from repro.kernels.autotune import CANDIDATES
@@ -74,7 +75,8 @@ def _kernel_fn(family, block_b, block_n, mod_m):
 
 
 @pytest.mark.parametrize("shape", sorted(SHAPES))
-@pytest.mark.parametrize("mod_m", [None, 4097], ids=["plain", "mod_m"])
+@pytest.mark.parametrize("mod_m", [None, 4097, 7_188_793_783],
+                         ids=["plain", "mod_m", "mod_m_wide"])
 @pytest.mark.parametrize("family", FAMILIES)
 def test_fused_kernel_compiles_for_v5e(one_chip, family, mod_m, shape):
     text = _compile_kernel(one_chip, family, *SHAPES[shape], mod_m)
@@ -106,3 +108,26 @@ def test_sharded_hasher_step_compiles_on_four_chips(topo):
     out = compiled.output_shardings
     assert out.mesh.size == 4 and out.spec[0] == "data", out
     assert np.prod(mesh.devices.shape) == 4
+
+
+def test_wide_probe_split_compiles_on_four_chips(topo):
+    """The dedup_docs_d4 filter's probes on a v5e host's four chips: the
+    wide mod_m epilogue in the kernel, then the (owner, local) split."""
+    mesh = make_mesh((4,), ("data",), devices=topo.devices)
+    B, N, K, m = 4096, 1024, 9, 7_188_793_783
+    m_local = -(-m // 4)
+    h = Hasher.from_spec(HashSpec(n_hashes=K, out_bits=64,
+                                  variable_length=True), max_len=N,
+                         plan=HashPlan(backend="pallas"))
+    rep, rows = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    h_sds = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep), h)
+
+    def step(hs, t, n):
+        r = ShardedHasher(hs, mesh).probe_indices(t, m, n)
+        return probe_owner_local(r, m, m_local, 4)
+
+    compiled = jax.jit(step).lower(
+        h_sds, jax.ShapeDtypeStruct((B, N), U32, sharding=rows),
+        jax.ShapeDtypeStruct((B,), jnp.int32, sharding=rows)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
