@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import dataclasses
 import warnings
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -64,10 +65,76 @@ def _bucket_shape(B: int, N: int, D: int) -> "tuple[int, int]":
     width rounded to the next power of two and rows to D * pow2(ceil(B/D))
     -- the D multiple makes the pure call's pad-to-multiple-of-D a no-op,
     so jit caches key on bucketed shapes only. Single source of the policy
-    for `ShardedHasher.hash_batch` and `DeviceShardedBloom._stage`."""
+    for `ShardedHasher.hash_batch`, and of the rows that
+    `DeviceShardedBloom._stage` buckets to (its widths are its groups')."""
     from ..kernels.autotune import pow2_at_least
 
     return D * pow2_at_least(max(1, -(-B // D))), pow2_at_least(max(N, 1))
+
+
+_LADDER_FLOOR = 32  # a device's rows in the ladder's last rung
+
+
+def _ladder(Bp: int, D: int) -> "tuple[int, ...]":
+    """Where the length order of a (Bp,)-row bucket over D devices may be
+    cut in two: after Bp/2, 3Bp/4, 7Bp/8, ... rows, the longer part halving
+    down to 32 x D rows. None where Bp <= 32 x D. Depends on (Bp, D)
+    alone, and every part is a multiple of D rows."""
+    floor = _LADDER_FLOOR * D
+    cuts, tail = [], Bp // 2
+    while Bp > floor and tail >= floor:
+        cuts.append(Bp - tail)
+        tail //= 2
+    return tuple(cuts)
+
+
+@jax.tree_util.register_pytree_node_class
+class TokenArena:
+    """A staged call's tokens: row groups laid back to back, device-major.
+
+    `data` is the (D, S_local) uint32 arena, sharded on the data axis, and
+    `groups` the static ((rows, width), ...) of each group on one device:
+    device d's part holds, group after group, its (rows, width) block of
+    every group. A pytree whose only leaf is `data`, so the group shapes
+    key the jit caches of the admission surfaces."""
+
+    def __init__(self, data, groups: "tuple[tuple[int, int], ...]"):
+        self.data = data
+        self.groups = groups
+
+    def tree_flatten(self):
+        return (self.data,), self.groups
+
+    @classmethod
+    def tree_unflatten(cls, groups, children):
+        return cls(children[0], groups)
+
+    def blocks(self, lens):
+        """Inside the `shard_map`: each group's (rows, width) tokens and
+        (rows,) lengths, cut from this device's part by static slices."""
+        flat, t, r = self.data.reshape(-1), 0, 0
+        for b, w in self.groups:
+            yield flat[t:t + b * w].reshape(b, w), lens[r:r + b]
+            t, r = t + b * w, r + b
+
+
+class Staged(NamedTuple):
+    """What `DeviceShardedBloom._stage` hands the device: the token arena,
+    the (Bp,) int32 lengths and bool row-valid mask in staged-row order
+    (device-major, then group by group), the true batch size, and each
+    item's staged row (None where staged rows are the items' own order)."""
+
+    tokens: TokenArena
+    lens: jax.Array
+    valid: jax.Array
+    n: int
+    rows: "np.ndarray | None"
+
+    def pick(self, out) -> np.ndarray:
+        """The items' entries of a (Bp,) per-staged-row output, in the
+        items' order."""
+        out = np.asarray(out)
+        return out[:self.n] if self.rows is None else out[self.rows]
 
 
 class ProbeBucketOverflow(RuntimeError):
@@ -382,6 +449,191 @@ def probe_owner_local(r, m: int, m_local: int, n_shards: int):
     return owner, local.astype(I32)
 
 
+def _admission_surfaces(mesh: Mesh, axis: str, m: int,
+                        plan: "limbs.ModPlan",
+                        transport: ProbeTransport) -> dict:
+    """The jitted `shard_map` surfaces of a `DeviceShardedBloom` of m bits
+    over `mesh`'s `axis`, by attribute name: `_add`, `_contains` and
+    `_admit` take (bits, (B, k, 2) pairs) from the host transport; the
+    in-graph `_add_dev`, `_contains_dev`, `_admit_dev` (all_gather) and
+    `_add_rt`, `_contains_rt`, `_admit_rt` (routed) take (bits, hasher,
+    `TokenArena`, lengths, valid). Built apart from a filter, so a surface
+    compiles for a described topology without allocating any bits."""
+    D = mesh_axis_size(mesh, axis)
+    m_local, ax = -(-m // D), axis
+
+    def _pairs(hs, toks, lens, valid):
+        """A device's `TokenArena` part -> (b, k) owner and local int32
+        arrays over its b staged rows: the Barrett reduction of each
+        group's accumulators, group after group, split by shard.
+        Padding rows carry the owner sentinel D: owned by no device,
+        their probes drop from every scatter and read as present
+        (dropped on host)."""
+        r = [hs.probe_indices(t, plan, n) for t, n in toks.blocks(lens)]
+        owner, local = probe_owner_local(
+            r[0] if len(r) == 1 else jnp.concatenate(r, axis=0),
+            m, m_local, D)
+        return jnp.where(valid[:, None], owner, I32(D)), local
+
+    def _local(p):
+        """(..., 2) (owner, local) pairs -> (local index, owned mask)
+        with other devices' probes sent to the drop slot m_local."""
+        owned = p[..., 0] == jax.lax.axis_index(ax)
+        return jnp.where(owned, p[..., 1], m_local), owned
+
+    def _miss(bits, p):
+        loc, owned = _local(p)
+        probe = jnp.where(owned, bits[jnp.clip(loc, 0, m_local - 1)],
+                          U8(1))
+        return jax.lax.psum(
+            jnp.sum((probe == 0).astype(I32), axis=1), ax)
+
+    def _pairs_in_graph(hs, toks, lens, valid):
+        """A device's staged rows -> (B, k, 2) int32 (owner, local) pairs of
+        every row: one all_gather of each device's pairs along the data
+        axis (the device-to-device twin of the old host round-trip)."""
+        return jax.lax.all_gather(
+            jnp.stack(_pairs(hs, toks, lens, valid), axis=-1), ax,
+            axis=0, tiled=True)
+
+    def add_body(bits, p):
+        loc, _ = _local(p)
+        return bits.at[loc.ravel()].set(U8(1), mode="drop")
+
+    def contains_body(bits, p):
+        return _miss(bits, p) == 0
+
+    def admit_body(bits, p):
+        present = _miss(bits, p) == 0
+        loc, _ = _local(p)
+        return bits.at[loc.ravel()].set(U8(1), mode="drop"), ~present
+
+    def add_body_dev(bits, hs, toks, lens, valid):
+        return add_body(bits, _pairs_in_graph(hs, toks, lens, valid))
+
+    def contains_body_dev(bits, hs, toks, lens, valid):
+        return contains_body(bits, _pairs_in_graph(hs, toks, lens, valid))
+
+    def admit_body_dev(bits, hs, toks, lens, valid):
+        return admit_body(bits, _pairs_in_graph(hs, toks, lens, valid))
+
+    # -- routed transport: owner-bucketed all_to_all probe exchange ----
+
+    def _route(hs, toks, lens, valid):
+        """Bucket this device's (b, k) probes by owning device and
+        exchange only owned probes: (recv_local, recv_row, overflow, b).
+
+        Probes pack into a static (D, cap, 2) int32 send buffer of
+        (local index, sender-local row) pairs, bucket d holding the
+        probes device d owns. Compaction is SCATTER-FREE (CPU scatters
+        serialize; this pack used to cost as much as the exchange): a
+        transposed per-destination running count (cumsum along the
+        contiguous axis), then a vectorized binary search -- bucket
+        d's j-th slot holds the first flat probe index whose running
+        count reaches j+1 -- so slots fill first-fit in flat-index
+        order and every buffer builds from gathers alone. Unused
+        capacity carries the drop slot m_local as its local index and
+        the b sentinel as its row; padding rows' probes carry the owner
+        sentinel D, the out-of-range bucket (HIGH, never negative: a
+        negative bucket would wrap and alias real buckets). One tiled
+        `all_to_all` then swaps bucket d to device d -- first-fit
+        order guarantees each received bucket's rows are
+        non-decreasing with the sentinel tail last, which is what lets
+        `_miss_rt` reduce per-row misses without a scatter. Probes
+        beyond `cap` never pack and raise the per-device overflow flag
+        -- the host-side settle path repairs via all_gather."""
+        owner, local = _pairs(hs, toks, lens, valid)
+        b, k = owner.shape
+        n_probes = b * k
+        cap = transport.capacity(n_probes, D)
+        dest = owner.reshape(n_probes)
+        onehot = dest[None, :] == jnp.arange(D, dtype=I32)[:, None]
+        pos = jnp.cumsum(onehot.astype(I32), axis=1)  # (D, n) running
+        counts = pos[:, -1]
+        overflow = jnp.any(counts > cap)
+        si = jax.vmap(lambda c: jnp.searchsorted(
+            c, jnp.arange(cap, dtype=I32) + 1))(pos)
+        ok = jnp.arange(cap, dtype=I32)[None, :] < counts[:, None]
+        sl = jnp.where(ok, local.reshape(n_probes)[
+            jnp.clip(si, 0, n_probes - 1)], I32(m_local))
+        sr = jnp.where(ok, si.astype(I32) // I32(k), I32(b))
+        recv = jax.lax.all_to_all(
+            jnp.stack([sl, sr], axis=-1), ax,
+            split_axis=0, concat_axis=0, tiled=True)
+        return recv[..., 0], recv[..., 1], overflow, b
+
+    def _scatter_rt(bits, recv_local):
+        """Set every received bit; the unused slots' drop index m_local
+        falls outside the shard (mode="drop")."""
+        return bits.at[recv_local.ravel()].set(U8(1), mode="drop")
+
+    def _miss_rt(bits, recv_local, recv_row, b):
+        """(Bp,) global miss counts: test received probes locally,
+        total per-row misses, ONE psum across devices. A row's total
+        miss count is 0 iff all k of its global bits are set --
+        identical verdict to the all_gather membership test even when
+        duplicate probe indices land in one bucket.
+
+        The per-row reduction is scatter-free: `_route`'s first-fit
+        pack means bucket s arrives with non-decreasing sender-local
+        rows (sentinel b in the tail), so each row's misses are one
+        contiguous run -- an exclusive prefix sum per bucket plus a
+        vectorized binary search for the run edges turns the reduction
+        into pure gathers, and block s's (b,) counts land at global
+        rows [s*b, (s+1)*b) by plain reshape (device s only ever sends
+        its own rows)."""
+        probe = jnp.where(recv_local < m_local,
+                          bits[jnp.minimum(recv_local, m_local - 1)],
+                          U8(1))
+        miss = (probe == 0).astype(I32)  # (D, cap)
+        cs = jnp.concatenate(
+            [jnp.zeros((D, 1), I32), jnp.cumsum(miss, axis=1)], axis=1)
+        edges = jax.vmap(lambda r: jnp.searchsorted(
+            r, jnp.arange(b + 1, dtype=I32)))(recv_row)
+        blk = jnp.arange(D, dtype=I32)[:, None]
+        counts = cs[blk, edges[:, 1:]] - cs[blk, edges[:, :-1]]
+        return jax.lax.psum(counts.reshape(b * D), ax)
+
+    def add_body_rt(bits, hs, toks, lens, valid):
+        recv_l, _, overflow, _ = _route(hs, toks, lens, valid)
+        return _scatter_rt(bits, recv_l), overflow[None]
+
+    def contains_body_rt(bits, hs, toks, lens, valid):
+        recv_l, recv_row, overflow, b = _route(hs, toks, lens, valid)
+        present = _miss_rt(bits, recv_l, recv_row, b) == 0
+        return present, overflow[None]
+
+    def admit_body_rt(bits, hs, toks, lens, valid):
+        recv_l, recv_row, overflow, b = _route(hs, toks, lens, valid)
+        present = _miss_rt(bits, recv_l, recv_row, b) == 0
+        return _scatter_rt(bits, recv_l), ~present, overflow[None]
+
+    sm = lambda body, out_specs: jax.jit(jax.shard_map(  # noqa: E731
+        body, mesh=mesh, in_specs=(P(ax), P()),
+        out_specs=out_specs, check_vma=False))
+    # in-graph surfaces: the hasher rides as a replicated pytree operand
+    # (like ShardedHasher), tokens/lengths/valid partition over the axis
+    smg = lambda body, out_specs: jax.jit(jax.shard_map(  # noqa: E731
+        body, mesh=mesh,
+        in_specs=(P(ax), P(), P(ax), P(ax), P(ax)),
+        out_specs=out_specs, check_vma=False))
+    return {
+        "_add": sm(add_body, P(ax)),
+        "_contains": sm(contains_body, P()),
+        "_admit": sm(admit_body, (P(ax), P())),
+        "_add_dev": smg(add_body_dev, P(ax)),
+        "_contains_dev": smg(contains_body_dev, P()),
+        "_admit_dev": smg(admit_body_dev, (P(ax), P())),
+        # routed surfaces: same operand layout; overflow flags come back
+        # per-device (out_spec P(axis) over a (1,) bool) so reading them
+        # never adds a collective to the launch.
+        "_add_rt": smg(add_body_rt, (P(ax), P(ax))),
+        "_contains_rt": smg(contains_body_rt, (P(), P(ax))),
+        "_admit_rt": smg(admit_body_rt, (P(ax), P(), P(ax))),
+    }
+
+
+
 class DeviceShardedBloom:
     """k-probe Bloom filter whose bit array is range-partitioned over the
     mesh data axis: device d owns global bits [d*m_local, (d+1)*m_local).
@@ -440,37 +692,63 @@ class DeviceShardedBloom:
     all_gather. Counted per staged call of `add_batch`, `contains_batch`
     and `check_and_add_batch` (the legacy `"host"` transport stages
     nothing): `calls`; `items`, the real rows B; `real_tokens`, the sum of
-    the item lengths; `staged_tokens`, Bp x Np of the pow2 bucket the batch
-    was padded to. `staged_tokens / real_tokens` is the padding waste: the
-    factor by which the staged, copied and hashed bytes exceed the input.
-    `stage_reuses`: staged calls written into the kept bucket buffer;
-    `stage_allocs`: staged calls that had to allocate one (the first call,
-    and every change of bucket shape). Counted per in-graph launch, from
-    shapes alone (no sync): `exchange_slots`, the slots of one device's
-    routed send buffer (D x capacity, routed launches only), and
-    `exchange_bytes`, the bytes of one device's send buffer on every
-    in-graph launch, replays included (8 a slot or pair: routed, D x
-    capacity (local, row) slots; all_gather, its (B/D) x k pairs).
+    the item lengths; `staged_tokens`, the slots of the call's row groups
+    (rows x width, summed); `stage_groups`, the groups it launched (1
+    where the call stays one bucket, else 2). `staged_tokens / real_tokens` is the
+    padding waste: the factor by which the staged, copied and hashed bytes
+    exceed the input. `stage_reuses`: staged calls written into the kept
+    arena; `stage_allocs`: staged calls that had to allocate one (the
+    first call, and every change of the groups' shapes). Counted per
+    in-graph launch, from shapes alone (no sync): `exchange_slots`, the
+    slots of one device's routed send buffer (D x capacity, routed
+    launches only), and `exchange_bytes`, the bytes of one device's send
+    buffer on every in-graph launch, replays included (8 a slot or pair:
+    routed, D x capacity (local, row) slots; all_gather, its (B/D) x k
+    pairs).
 
-    Host staging keeps ONE (Bp, Np) uint32 bucket buffer, Bp x Np x 4 bytes
-    of host memory (268 MB for a 4096 x 16384 bucket), for the last bucket
-    shape seen: each call writes every item's tokens once, straight into
-    it, and zeroes only what the last call wrote beyond the new lengths, so
-    the staged bytes equal a fresh zero-padded stack. A call with another
-    bucket shape drops it and allocates one of the new shape. The device
-    gets its own copy (`_stage`), rows split over the mesh as the launch
-    takes them, so staged arrays held for an overflow replay never see a
-    later call's rewrite.
+    Host staging (`_stage`) cuts a call's length-sorted rows in two. Rows
+    bucket to Bp = D x pow2(ceil(B/D)), as `_bucket_shape` gives; a stable
+    argsort orders them by length (padding rows have length 0), and the
+    order is cut at one rung of a fixed halving ladder (`_ladder`: after
+    Bp/2, 3Bp/4, ... rows, the longer part halving down to 32 x D rows):
+    the rung that stages the fewest slots, each part as wide as the power
+    of two above its own longest row. So the longest 1/2, 1/4, ... of the
+    rows share a group of their own, and the rest is not padded to their
+    width. Each group's d-th 1/D goes to device d. The call stays one
+    Bp x Np bucket, rows in their own order, where Bp <= 32 x D
+    (serve-shaped batches) or where no cut stages fewer slots (rows of one
+    width class, a dense (B, N) batch), so a call never stages more slots
+    than that bucket. Two groups, not more: each group shape is a kernel
+    of its own to trace and lower (about 1.5 s on a v5e host, in every
+    process, since only the compiled program is cached). A compile is
+    keyed on the groups' (rows, width), pow2 values, so traces stay
+    bounded as under one bucket.
+
+    The groups lie back to back in ONE kept host arena, device-major: a
+    (D, S_local) uint32 `TokenArena` whose device-d part holds its block
+    of every group in turn, so a call makes three host-to-device copies
+    (arena, lengths, valid mask) whatever its group count. Inside the
+    `shard_map` each device cuts its groups out by static slices, hashes
+    them one by one and concatenates their (owner, local) probes in group
+    order into the (B/D, k) a device routes, tests and scatters in one
+    launch. Verdicts come back per staged row; `Staged.pick` maps them to
+    the items through the argsort. The arena is reused while the groups'
+    shapes hold (a new one is allocated when they change): each call
+    writes every item's tokens once, straight into its staged row, and
+    zeroes only what the last call wrote beyond the new lengths, so the
+    staged bytes equal a fresh zero-padded stack. The device gets its own
+    copy, so staged arrays held for an overflow replay never see a later
+    call's rewrite.
 
     Spans (`jax.profiler.TraceAnnotation`, seen only under the profiler):
-    `bloom.check_and_add_batch` (metadata `call`, `items`, `width`,
-    `shards`, `m_bits`) holds
+    `bloom.check_and_add_batch` (metadata `call`, `items`, `width` (the
+    widest group), `groups`, `shards`, `m_bits`) holds
     `bloom.stage`, `bloom.launch`, `bloom.readback` and, on a routed
     overflow, `bloom.overflow_replay`; `bloom.stage` holds, in order,
-    `bloom.stage.stack` (the item lengths, and on a reuse the tokens
-    written into the kept buffer), `bloom.stage.pad` (on a reuse the stale
-    row tails and rows zeroed; on a shape change the new buffer allocated
-    and the tokens written into its fresh pages) and
+    `bloom.stage.stack` (the item lengths and their order, and on a reuse
+    the tokens written into the kept arena), `bloom.stage.pad` (on a reuse
+    the stale row tails and rows zeroed; on a shape change the new arena
+    allocated and the tokens written into its fresh pages) and
     `bloom.stage.to_device` (three host-to-device copies, plus the
     device-side copy of the tokens).
     """
@@ -521,181 +799,17 @@ class DeviceShardedBloom:
         self._pending: list = []  # routed adds with unread overflow flags
         self.stats = {"overflow_fallbacks": 0, "calls": 0, "items": 0,
                       "real_tokens": 0, "staged_tokens": 0,
+                      "stage_groups": 0,
                       "stage_reuses": 0, "stage_allocs": 0,
                       "exchange_slots": 0, "exchange_bytes": 0}
-        # the kept bucket buffer, its row views, how far each row was
-        # written, and the device copy of its last contents (`_stage`)
-        self._buf = self._buf_rows = self._buf_lens = self._buf_fence = None
+        # the kept host arena, its groups, the staged row of each length
+        # rank, its row views, how far each row was written, and the device
+        # copy of its last contents (`_stage`)
+        self._buf = self._buf_groups = self._buf_ranks = None
+        self._buf_rows = self._buf_lens = self._buf_fence = None
 
-        m, m_local, ax, plan = self.m, self.m_local, axis, self.plan
-        transport = self.transport
-
-        def _pairs(hs, toks, lens, valid):
-            """(b, N) rows -> (b, k) owner and local int32 arrays: the
-            Barrett reduction of each device's own accumulators, split by
-            shard. Padding rows carry the owner sentinel D: owned by no
-            device, their probes drop from every scatter and read as
-            present (sliced off on host)."""
-            owner, local = probe_owner_local(
-                hs.probe_indices(toks, plan, lens), m, m_local, D)
-            return jnp.where(valid[:, None], owner, I32(D)), local
-
-        def _local(p):
-            """(..., 2) (owner, local) pairs -> (local index, owned mask)
-            with other devices' probes sent to the drop slot m_local."""
-            owned = p[..., 0] == jax.lax.axis_index(ax)
-            return jnp.where(owned, p[..., 1], m_local), owned
-
-        def _miss(bits, p):
-            loc, owned = _local(p)
-            probe = jnp.where(owned, bits[jnp.clip(loc, 0, m_local - 1)],
-                              U8(1))
-            return jax.lax.psum(
-                jnp.sum((probe == 0).astype(I32), axis=1), ax)
-
-        def _pairs_in_graph(hs, toks, lens, valid):
-            """(b_local, N) rows -> (B, k, 2) int32 (owner, local) pairs of
-            every row: one all_gather of each device's pairs along the data
-            axis (the device-to-device twin of the old host round-trip)."""
-            return jax.lax.all_gather(
-                jnp.stack(_pairs(hs, toks, lens, valid), axis=-1), ax,
-                axis=0, tiled=True)
-
-        def add_body(bits, p):
-            loc, _ = _local(p)
-            return bits.at[loc.ravel()].set(U8(1), mode="drop")
-
-        def contains_body(bits, p):
-            return _miss(bits, p) == 0
-
-        def admit_body(bits, p):
-            present = _miss(bits, p) == 0
-            loc, _ = _local(p)
-            return bits.at[loc.ravel()].set(U8(1), mode="drop"), ~present
-
-        def add_body_dev(bits, hs, toks, lens, valid):
-            return add_body(bits, _pairs_in_graph(hs, toks, lens, valid))
-
-        def contains_body_dev(bits, hs, toks, lens, valid):
-            return contains_body(bits, _pairs_in_graph(hs, toks, lens, valid))
-
-        def admit_body_dev(bits, hs, toks, lens, valid):
-            return admit_body(bits, _pairs_in_graph(hs, toks, lens, valid))
-
-        # -- routed transport: owner-bucketed all_to_all probe exchange ----
-
-        def _route(hs, toks, lens, valid):
-            """Bucket this device's (b, k) probes by owning device and
-            exchange only owned probes: (recv_local, recv_row, overflow, b).
-
-            Probes pack into a static (D, cap, 2) int32 send buffer of
-            (local index, sender-local row) pairs, bucket d holding the
-            probes device d owns. Compaction is SCATTER-FREE (CPU scatters
-            serialize; this pack used to cost as much as the exchange): a
-            transposed per-destination running count (cumsum along the
-            contiguous axis), then a vectorized binary search -- bucket
-            d's j-th slot holds the first flat probe index whose running
-            count reaches j+1 -- so slots fill first-fit in flat-index
-            order and every buffer builds from gathers alone. Unused
-            capacity carries the drop slot m_local as its local index and
-            the b sentinel as its row; padding rows' probes carry the owner
-            sentinel D, the out-of-range bucket (HIGH, never negative: a
-            negative bucket would wrap and alias real buckets). One tiled
-            `all_to_all` then swaps bucket d to device d -- first-fit
-            order guarantees each received bucket's rows are
-            non-decreasing with the sentinel tail last, which is what lets
-            `_miss_rt` reduce per-row misses without a scatter. Probes
-            beyond `cap` never pack and raise the per-device overflow flag
-            -- the host-side settle path repairs via all_gather."""
-            owner, local = _pairs(hs, toks, lens, valid)
-            b, k = owner.shape
-            n_probes = b * k
-            cap = transport.capacity(n_probes, D)
-            dest = owner.reshape(n_probes)
-            onehot = dest[None, :] == jnp.arange(D, dtype=I32)[:, None]
-            pos = jnp.cumsum(onehot.astype(I32), axis=1)  # (D, n) running
-            counts = pos[:, -1]
-            overflow = jnp.any(counts > cap)
-            si = jax.vmap(lambda c: jnp.searchsorted(
-                c, jnp.arange(cap, dtype=I32) + 1))(pos)
-            ok = jnp.arange(cap, dtype=I32)[None, :] < counts[:, None]
-            sl = jnp.where(ok, local.reshape(n_probes)[
-                jnp.clip(si, 0, n_probes - 1)], I32(m_local))
-            sr = jnp.where(ok, si.astype(I32) // I32(k), I32(b))
-            recv = jax.lax.all_to_all(
-                jnp.stack([sl, sr], axis=-1), ax,
-                split_axis=0, concat_axis=0, tiled=True)
-            return recv[..., 0], recv[..., 1], overflow, b
-
-        def _scatter_rt(bits, recv_local):
-            """Set every received bit; the unused slots' drop index m_local
-            falls outside the shard (mode="drop")."""
-            return bits.at[recv_local.ravel()].set(U8(1), mode="drop")
-
-        def _miss_rt(bits, recv_local, recv_row, b):
-            """(Bp,) global miss counts: test received probes locally,
-            total per-row misses, ONE psum across devices. A row's total
-            miss count is 0 iff all k of its global bits are set --
-            identical verdict to the all_gather membership test even when
-            duplicate probe indices land in one bucket.
-
-            The per-row reduction is scatter-free: `_route`'s first-fit
-            pack means bucket s arrives with non-decreasing sender-local
-            rows (sentinel b in the tail), so each row's misses are one
-            contiguous run -- an exclusive prefix sum per bucket plus a
-            vectorized binary search for the run edges turns the reduction
-            into pure gathers, and block s's (b,) counts land at global
-            rows [s*b, (s+1)*b) by plain reshape (device s only ever sends
-            its own rows)."""
-            probe = jnp.where(recv_local < m_local,
-                              bits[jnp.minimum(recv_local, m_local - 1)],
-                              U8(1))
-            miss = (probe == 0).astype(I32)  # (D, cap)
-            cs = jnp.concatenate(
-                [jnp.zeros((D, 1), I32), jnp.cumsum(miss, axis=1)], axis=1)
-            edges = jax.vmap(lambda r: jnp.searchsorted(
-                r, jnp.arange(b + 1, dtype=I32)))(recv_row)
-            blk = jnp.arange(D, dtype=I32)[:, None]
-            counts = cs[blk, edges[:, 1:]] - cs[blk, edges[:, :-1]]
-            return jax.lax.psum(counts.reshape(b * D), ax)
-
-        def add_body_rt(bits, hs, toks, lens, valid):
-            recv_l, _, overflow, _ = _route(hs, toks, lens, valid)
-            return _scatter_rt(bits, recv_l), overflow[None]
-
-        def contains_body_rt(bits, hs, toks, lens, valid):
-            recv_l, recv_row, overflow, b = _route(hs, toks, lens, valid)
-            present = _miss_rt(bits, recv_l, recv_row, b) == 0
-            return present, overflow[None]
-
-        def admit_body_rt(bits, hs, toks, lens, valid):
-            recv_l, recv_row, overflow, b = _route(hs, toks, lens, valid)
-            present = _miss_rt(bits, recv_l, recv_row, b) == 0
-            return _scatter_rt(bits, recv_l), ~present, overflow[None]
-
-        sm = lambda body, out_specs: jax.jit(jax.shard_map(  # noqa: E731
-            body, mesh=self.mesh, in_specs=(P(self.axis), P()),
-            out_specs=out_specs, check_vma=False))
-        self._add = sm(add_body, P(self.axis))
-        self._contains = sm(contains_body, P())
-        self._admit = sm(admit_body, (P(self.axis), P()))
-        # in-graph surfaces: the hasher rides as a replicated pytree operand
-        # (like ShardedHasher), tokens/lengths/valid partition over the axis
-        smg = lambda body, out_specs: jax.jit(jax.shard_map(  # noqa: E731
-            body, mesh=self.mesh,
-            in_specs=(P(self.axis), P(), P(self.axis), P(self.axis),
-                      P(self.axis)),
-            out_specs=out_specs, check_vma=False))
-        self._add_dev = smg(add_body_dev, P(self.axis))
-        self._contains_dev = smg(contains_body_dev, P())
-        self._admit_dev = smg(admit_body_dev, (P(self.axis), P()))
-        # routed surfaces: same operand layout; overflow flags come back
-        # per-device (out_spec P(axis) over a (1,) bool) so reading them
-        # never adds a collective to the launch.
-        self._add_rt = smg(add_body_rt, (P(self.axis), P(self.axis)))
-        self._contains_rt = smg(contains_body_rt, (P(), P(self.axis)))
-        self._admit_rt = smg(
-            admit_body_rt, (P(self.axis), P(), P(self.axis)))
+        vars(self).update(_admission_surfaces(
+            self.mesh, axis, self.m, self.plan, self.transport))
 
     @property
     def n_shards(self) -> int:
@@ -732,10 +846,10 @@ class DeviceShardedBloom:
             return
         self.stats["overflow_fallbacks"] += len(replay)
         with _span("bloom.overflow_replay"):
-            for toks, lens, valid in replay:
-                self._count_exchange(toks.shape[0], routed=False)
+            for st in replay:
+                self._count_exchange(st.lens.shape[0], routed=False)
                 self._bits = self._add_dev(
-                    self._bits, self.sharded.hasher, toks, lens, valid)
+                    self._bits, self.sharded.hasher, *st[:3])
         if self.transport.on_overflow == "error":
             raise ProbeBucketOverflow(
                 f"{len(replay)} routed add batch(es) overflowed the static "
@@ -769,14 +883,52 @@ class DeviceShardedBloom:
         else:
             self.stats["exchange_bytes"] += n * 8
 
-    def _stage(self, items):
-        """Stage host items for the in-graph path: (Bp, Np) uint32 tokens,
-        (Bp,) int32 lengths, (Bp,) bool row-valid mask, true batch size B.
-        Shapes bucket via `_bucket_shape` (the same bounded-trace policy as
-        `ShardedHasher.hash_batch`); padding rows are invalid -- their
-        probes carry the owner sentinel D in-graph. Tokens go once into the
-        kept bucket buffer (class docstring): row i holds item i in columns
-        [0, len_i) and zeros elsewhere, as a fresh zero-padded stack would."""
+    def _groups(self, lens):
+        """(groups, order) for a call's (Bp,) padded lengths: the
+        ((rows, width), ...) of each group on one device, and the stable
+        length order of the rows, or None where the rows keep their own
+        order in one Bp x Np bucket. The length order is cut in two at the
+        `_ladder` rung that stages the fewest slots, each part as wide as
+        the power of two above its own longest row; one bucket where there
+        is no rung, or no cut stages fewer slots (rows of one width class,
+        a dense batch)."""
+        from ..kernels.autotune import pow2_at_least
+
+        D, Bp = self.n_shards, len(lens)
+        top = pow2_at_least(max(int(lens.max()), 1))
+        cuts = _ladder(Bp, D)
+        if cuts:
+            order = np.argsort(lens, kind="stable")
+            widths = [pow2_at_least(max(n, 1))
+                      for n in lens[order[np.asarray(cuts) - 1]].tolist()]
+            slots, c, w = min((c * w + (Bp - c) * top, c, w)
+                              for c, w in zip(cuts, widths))
+            if w < top:
+                return ((c // D, w), ((Bp - c) // D, top)), order
+        return ((Bp // D, top),), None
+
+    def _rank_rows(self, groups) -> np.ndarray:
+        """(Bp,) staged row of each length rank: a group's ranks go in D
+        equal runs, the d-th to the group's block on device d. Staged row
+        d x (Bp/D) + j is row j of device d's part of the arena."""
+        D, R = self.n_shards, sum(b for b, _ in groups)
+        out, off = [], 0
+        for b, _ in groups:
+            t = np.arange(b * D)
+            out.append(t // b * R + off + t % b)
+            off += b
+        return np.concatenate(out)
+
+    def _stage(self, items) -> Staged:
+        """Stage host items for the in-graph path (class docstring). Rows
+        bucket to `_bucket_shape`'s pow2 row count Bp; `_groups` either
+        keeps them in order in one Bp x Np bucket, or orders them by
+        length and cuts them into two groups, each as wide as the power of
+        two above its own longest row. Padding rows have length
+        0 and are invalid -- their probes carry the owner sentinel D
+        in-graph. Tokens go once into the kept host arena: a staged row
+        holds its item in columns [0, len) and zeros elsewhere, as a fresh
+        zero-padded stack would."""
         with _span("bloom.stage"):
             with _span("bloom.stage.stack"):
                 if self._buf_fence is not None:
@@ -787,19 +939,27 @@ class DeviceShardedBloom:
                     src = [r if isinstance(r, np.ndarray) and r.ndim == 1
                            else np.atleast_1d(np.asarray(r)) for r in items]
                     lens = np.fromiter(map(len, src), np.int64, len(src))
-                    N = int(lens.max())
                 else:
                     src = np.atleast_2d(np.asarray(items))
-                    N = src.shape[1]
-                    lens = np.full(src.shape[0], N, np.int64)
+                    lens = np.full(src.shape[0], src.shape[1], np.int64)
                 B = len(lens)
-                Bp, Np = _bucket_shape(B, N, self.n_shards)
-                reuse = self._buf is not None and self._buf.shape == (Bp, Np)
-                if reuse:
-                    self._fill(src)
-            with _span("bloom.stage.pad"):
+                Bp, _ = _bucket_shape(B, 1, self.n_shards)
                 lens_p = np.zeros(Bp, np.int32)
                 lens_p[:B] = lens
+                groups, order = self._groups(lens_p)
+                reuse = self._buf is not None and self._buf_groups == groups
+                ranks = self._buf_ranks if reuse else self._rank_rows(groups)
+                at = None  # each item's staged row, where not its own
+                if order is not None:
+                    rows = np.empty(Bp, np.int64)
+                    rows[order] = ranks
+                    at = rows[:B]
+                    lens_s = np.empty_like(lens_p)
+                    lens_s[rows] = lens_p
+                    lens_p = lens_s
+                if reuse:
+                    self._fill(src, at)
+            with _span("bloom.stage.pad"):
                 if reuse:
                     prev = self._buf_lens
                     stale = np.flatnonzero(prev > lens_p)
@@ -809,37 +969,58 @@ class DeviceShardedBloom:
                                          prev[stale].tolist()):
                         views[i][lo:hi] = 0
                 else:
-                    self._buf = np.zeros((Bp, Np), np.uint32)
-                    self._buf_rows = list(self._buf)
-                    self._fill(src)
+                    self._alloc(groups, ranks)
+                    self._fill(src, at)
                 self._buf_lens = lens_p
                 valid = np.zeros(Bp, bool)
-                valid[:B] = True
+                valid[slice(B) if at is None else at] = True
             st = self.stats
             st["calls"] += 1
             st["items"] += B
             st["real_tokens"] += int(lens.sum())
-            st["staged_tokens"] += Bp * Np
+            st["staged_tokens"] += self.n_shards * sum(b * w
+                                                       for b, w in groups)
+            st["stage_groups"] += len(groups)
             st["stage_reuses" if reuse else "stage_allocs"] += 1
             with _span("bloom.stage.to_device"):
-                self.sharded.ensure(Np)
+                self.sharded.ensure(max(w for _, w in groups))
                 # a runtime may zero-copy host memory (the CPU client does,
                 # for aligned arrays): the device-side copy is what no later
-                # rewrite of the buffer can reach. Rows go straight to the
-                # device that hashes them.
+                # rewrite of the arena can reach. Each device's part goes
+                # straight to it.
                 toks = jnp.copy(jax.device_put(self._buf, self._rows))
                 self._buf_fence = toks
                 lens_d, valid_d = jax.device_put((lens_p, valid), self._rows)
-                return toks, lens_d, valid_d, B
+                return Staged(TokenArena(toks, groups), lens_d, valid_d, B, at)
 
-    def _fill(self, src) -> None:
-        """Write item tokens into the kept buffer, casting in place: a
-        ragged list row by row, a dense (B, N) array in one assignment."""
-        if isinstance(src, np.ndarray):
-            self._buf[:src.shape[0], :src.shape[1]] = src
-            return
-        for view, row in zip(self._buf_rows, src):
-            view[:len(row)] = row
+    def _alloc(self, groups, ranks) -> None:
+        """A zeroed (D, S_local) host arena for `groups`, and the view of
+        every staged row into it, in staged-row order."""
+        D = self.n_shards
+        self._buf = np.zeros((D, sum(b * w for b, w in groups)), np.uint32)
+        self._buf_groups, self._buf_ranks = groups, ranks
+        self._buf_rows = views = []
+        for part in self._buf:
+            off = 0
+            for b, w in groups:
+                views.extend(part[off:off + b * w].reshape(b, w))
+                off += b * w
+
+    def _fill(self, src, at) -> None:
+        """Write item tokens into the kept arena, casting in place: a dense
+        (B, N) array in one assignment where the rows keep their order,
+        else row by row into each item's staged row (`at`)."""
+        views = self._buf_rows
+        if at is None:
+            if isinstance(src, np.ndarray):
+                (_, w), = self._buf_groups
+                self._buf.reshape(-1, w)[:src.shape[0], :src.shape[1]] = src
+                return
+            at = range(len(src))
+        else:
+            at = at.tolist()
+        for i, row in zip(at, src):
+            views[i][:len(row)] = row
 
     def owner_shards(self, items) -> np.ndarray:
         """(B,) home shard per item via the Lemire multiply-shift reduction
@@ -864,15 +1045,14 @@ class DeviceShardedBloom:
             self._bits = self._add(
                 self._bits, jnp.asarray(self._probes(items)))
             return
-        toks, lens, valid, _ = self._stage(items)
-        self._count_exchange(toks.shape[0], routed=kind == "routed")
+        st = self._stage(items)
+        self._count_exchange(st.lens.shape[0], routed=kind == "routed")
+        args = (self.sharded.hasher, *st[:3])
         if kind == "all_gather":
-            self._bits = self._add_dev(
-                self._bits, self.sharded.hasher, toks, lens, valid)
+            self._bits = self._add_dev(self._bits, *args)
             return
-        self._bits, flag = self._add_rt(
-            self._bits, self.sharded.hasher, toks, lens, valid)
-        self._pending.append((flag, (toks, lens, valid)))
+        self._bits, flag = self._add_rt(self._bits, *args)
+        self._pending.append((flag, st))
         if len(self._pending) >= self._settle_every:
             self._settle()
 
@@ -887,19 +1067,17 @@ class DeviceShardedBloom:
             return np.asarray(
                 self._contains(self._bits, jnp.asarray(self._probes(items))))
         self._settle()
-        toks, lens, valid, B = self._stage(items)
-        self._count_exchange(toks.shape[0], routed=kind == "routed")
+        st = self._stage(items)
+        self._count_exchange(st.lens.shape[0], routed=kind == "routed")
+        args = (self.sharded.hasher, *st[:3])
         if kind == "all_gather":
-            return np.asarray(self._contains_dev(
-                self._bits, self.sharded.hasher, toks, lens, valid))[:B]
-        verdict, flag = self._contains_rt(
-            self._bits, self.sharded.hasher, toks, lens, valid)
+            return st.pick(self._contains_dev(self._bits, *args))
+        verdict, flag = self._contains_rt(self._bits, *args)
         if bool(np.asarray(flag).any()):
             self._overflowed("contains_batch")
-            self._count_exchange(toks.shape[0], routed=False)
-            verdict = self._contains_dev(
-                self._bits, self.sharded.hasher, toks, lens, valid)
-        return np.asarray(verdict)[:B]
+            self._count_exchange(st.lens.shape[0], routed=False)
+            verdict = self._contains_dev(self._bits, *args)
+        return st.pick(verdict)
 
     def check_and_add_batch(self, items) -> np.ndarray:
         """(B,) admission mask in ONE fused launch + ONE psum: True where
@@ -916,31 +1094,33 @@ class DeviceShardedBloom:
             return np.asarray(admitted)
         with _span("bloom.check_and_add_batch") as span:
             self._settle()
-            toks, lens, valid, B = self._stage(items)
-            span.set_metadata(call=self.stats["calls"], items=B,
-                              width=toks.shape[1], shards=self.n_shards,
+            st = self._stage(items)
+            groups = st.tokens.groups
+            span.set_metadata(call=self.stats["calls"], items=st.n,
+                              width=max(w for _, w in groups),
+                              groups=len(groups), shards=self.n_shards,
                               m_bits=self.m)
-            self._count_exchange(toks.shape[0], routed=kind == "routed")
-            args = (self.sharded.hasher, toks, lens, valid)
+            self._count_exchange(st.lens.shape[0], routed=kind == "routed")
+            args = (self.sharded.hasher, *st[:3])
             if kind == "all_gather":
                 with _span("bloom.launch"):
                     self._bits, admitted = self._admit_dev(self._bits, *args)
                 with _span("bloom.readback"):
-                    return np.asarray(admitted)[:B]
+                    return st.pick(admitted)
             with _span("bloom.launch"):
                 new_bits, admitted, flag = self._admit_rt(self._bits, *args)
             with _span("bloom.readback"):
                 overflow = bool(np.asarray(flag).any())
                 if not overflow:
-                    admitted = np.asarray(admitted)[:B]
+                    admitted = st.pick(admitted)
             if overflow:
                 # truncated exchange: discard the partial scatter/verdicts
                 # and rerun against the untouched pre-call bits via all_gather
                 with _span("bloom.overflow_replay"):
                     self._overflowed("check_and_add_batch")
-                    self._count_exchange(toks.shape[0], routed=False)
+                    self._count_exchange(st.lens.shape[0], routed=False)
                     new_bits, admitted = self._admit_dev(self._bits, *args)
-                    admitted = np.asarray(admitted)[:B]
+                    admitted = st.pick(admitted)
             self._bits = new_bits
             return admitted
 

@@ -1,7 +1,7 @@
 """`DeviceShardedBloom`'s own instruments on a mesh of one, kernels in
-interpret mode: the padding counters in `stats` are exact on every in-graph
-path, and one admission call under the profiler writes the documented
-spans, nested as documented, with its metadata."""
+interpret mode: the padding and staging-group counters in `stats` are
+exact on every in-graph path, and one admission call under the profiler
+writes the documented spans, nested as documented, with its metadata."""
 import os
 
 import jax
@@ -49,6 +49,12 @@ def _staged(lengths):
     return Bp * Np
 
 
+# a call past 32 rows: 128 staged rows, length-sorted, cut into groups of
+# 96 and 32 rows, as wide as 4 and 128 tokens
+LADDER = [3] * 50 + [100] * 20
+LADDER_SLOTS = 96 * 4 + 32 * 128
+
+
 def _spans(tmp_path, fn) -> list:
     """(name, start_ns, end_ns, stats) of every `bloom.*` host event that
     `fn()` writes under the profiler, in start order."""
@@ -79,6 +85,7 @@ def test_counters_are_exact_on_every_in_graph_path(transport):
     k, routed = bloom.k, transport == "routed"
     assert bloom.stats == {"overflow_fallbacks": 0, "calls": 0, "items": 0,
                            "real_tokens": 0, "staged_tokens": 0,
+                           "stage_groups": 0,
                            "stage_reuses": 0, "stage_allocs": 0,
                            "exchange_slots": 0, "exchange_bytes": 0}
     # three ragged batches and a dense one, all in the (8, 16) bucket
@@ -88,6 +95,7 @@ def test_counters_are_exact_on_every_in_graph_path(transport):
     assert bloom.stats == {"overflow_fallbacks": 0, "calls": 1, "items": 5,
                            "real_tokens": sum(a),
                            "staged_tokens": _staged(a),
+                           "stage_groups": 1,
                            "stage_reuses": 0, "stage_allocs": 1,
                            "exchange_slots": 8 * k * routed,
                            "exchange_bytes": 8 * k * 8}
@@ -116,6 +124,15 @@ def test_counters_are_exact_on_every_in_graph_path(transport):
     rows = 4 * 8 + 2 + 1 + 2 + 2 + 2  # staged rows of the nine calls
     assert bloom.stats["exchange_slots"] == rows * k * routed
     assert bloom.stats["exchange_bytes"] == rows * k * 8
+    assert bloom.stats["stage_groups"] == 9  # one bucket each
+    staged = bloom.stats["staged_tokens"]
+    bloom.check_and_add_batch(_ragged(LADDER))   # two groups
+    assert bloom.stats["stage_groups"] == 9 + 2
+    assert bloom.stats["staged_tokens"] == staged + LADDER_SLOTS
+    assert bloom.stats["real_tokens"] == (sum(a) + sum(b) + sum(c) + 75
+                                          + 40 + 3 + 33 + 66 + 10 + 22
+                                          + sum(LADDER))
+    assert bloom.stats["exchange_bytes"] == (rows + 128) * k * 8
 
 
 def test_host_transport_stages_nothing():
@@ -123,6 +140,7 @@ def test_host_transport_stages_nothing():
     bloom.check_and_add_batch(_ragged([4, 2]))
     assert bloom.stats == {"overflow_fallbacks": 0, "calls": 0, "items": 0,
                            "real_tokens": 0, "staged_tokens": 0,
+                           "stage_groups": 0,
                            "stage_reuses": 0, "stage_allocs": 0,
                            "exchange_slots": 0, "exchange_bytes": 0}
 
@@ -130,13 +148,16 @@ def test_host_transport_stages_nothing():
 def test_admission_call_writes_every_span_nested(tmp_path):
     bloom = _bloom()
     lengths = [6, 1, 9, 4, 3]
-    bloom.check_and_add_batch(_ragged(lengths))  # compile outside the trace
+    for n in (lengths, LADDER):  # compile outside the trace
+        bloom.check_and_add_batch(_ragged(n))
     spans = _spans(tmp_path, lambda: [bloom.check_and_add_batch(
-        _ragged(lengths)) for _ in range(2)])
+        _ragged(n)) for n in (lengths, LADDER)])
     outer = [e for e in spans if e[0] == "bloom.check_and_add_batch"]
     assert [e[3] for e in outer] == [
-        {"call": 2, "items": 5, "width": 16, "shards": 1, "m_bits": bloom.m},
-        {"call": 3, "items": 5, "width": 16, "shards": 1, "m_bits": bloom.m}]
+        {"call": 3, "items": 5, "width": 16, "groups": 1, "shards": 1,
+         "m_bits": bloom.m},
+        {"call": 4, "items": 70, "width": 128, "groups": 2, "shards": 1,
+         "m_bits": bloom.m}]
     for call in outer:
         inside = [e for e in spans if _within(e, call)]
         assert sorted(e[0] for e in inside) == sorted(NESTING)

@@ -164,25 +164,29 @@ def test_sharded_bloom_probes_in_graph_zero_syncs_one_psum():
     BOTH in-graph transports. The probe collective (all_gather, or the
     routed all_to_all) replaces the old host round-trip -- device-to-device,
     not a sync. Routed surfaces carry exactly one all_to_all and NO
-    all_gather (the bytes claim of DESIGN.md §7)."""
+    all_gather (the bytes claim of DESIGN.md §7). The same holds where a
+    call stages one bucket and where it stages two row groups, each
+    hashed on its own."""
     dsb = DeviceShardedBloom(n_items=128, fp_rate=1e-2)
-    toks, lens, valid, _ = dsb._stage(_ragged(9, 12))
-    args = (dsb.bits, dsb.sharded.hasher, toks, lens, valid)
-    j_add = str(jax.make_jaxpr(dsb._add_dev)(*args))
-    j_con = str(jax.make_jaxpr(dsb._contains_dev)(*args))
-    j_adm = str(jax.make_jaxpr(dsb._admit_dev)(*args))
-    j_add_rt = str(jax.make_jaxpr(dsb._add_rt)(*args))
-    j_con_rt = str(jax.make_jaxpr(dsb._contains_rt)(*args))
-    j_adm_rt = str(jax.make_jaxpr(dsb._admit_rt)(*args))
-    for jaxpr in (j_add, j_con, j_adm, j_add_rt, j_con_rt, j_adm_rt):
-        for bad in ("callback", "host_callback", "device_get", "infeed"):
-            assert bad not in jaxpr, f"host primitive {bad!r} in jaxpr"
-    assert j_add.count("psum") == 0 and j_add_rt.count("psum") == 0
-    assert j_con.count("psum") == 1 and j_con_rt.count("psum") == 1
-    assert j_adm.count("psum") == 1 and j_adm_rt.count("psum") == 1
-    for jaxpr in (j_add_rt, j_con_rt, j_adm_rt):
-        assert jaxpr.count("all_to_all") == 1
-        assert "all_gather" not in jaxpr
+    one, grouped = dsb._stage(_ragged(9, 12)), dsb._stage(_ragged(70, 40))
+    assert len(one.tokens.groups) == 1 and len(grouped.tokens.groups) == 2
+    for st in (one, grouped):
+        args = (dsb.bits, dsb.sharded.hasher, *st[:3])
+        j_add = str(jax.make_jaxpr(dsb._add_dev)(*args))
+        j_con = str(jax.make_jaxpr(dsb._contains_dev)(*args))
+        j_adm = str(jax.make_jaxpr(dsb._admit_dev)(*args))
+        j_add_rt = str(jax.make_jaxpr(dsb._add_rt)(*args))
+        j_con_rt = str(jax.make_jaxpr(dsb._contains_rt)(*args))
+        j_adm_rt = str(jax.make_jaxpr(dsb._admit_rt)(*args))
+        for jaxpr in (j_add, j_con, j_adm, j_add_rt, j_con_rt, j_adm_rt):
+            for bad in ("callback", "host_callback", "device_get", "infeed"):
+                assert bad not in jaxpr, f"host primitive {bad!r} in jaxpr"
+        assert j_add.count("psum") == 0 and j_add_rt.count("psum") == 0
+        assert j_con.count("psum") == 1 and j_con_rt.count("psum") == 1
+        assert j_adm.count("psum") == 1 and j_adm_rt.count("psum") == 1
+        for jaxpr in (j_add_rt, j_con_rt, j_adm_rt):
+            assert jaxpr.count("all_to_all") == 1
+            assert "all_gather" not in jaxpr
 
 
 def test_sharded_bloom_in_graph_matches_host_mod_path():

@@ -100,8 +100,8 @@ def test_routed_sentinel_rows_owned_by_no_device():
     verdict vector (sliced off by the host wrapper)."""
     dsb = DeviceShardedBloom(n_items=128, fp_rate=1e-2,
                              probe_transport="routed")
-    toks, lens, valid, B = dsb._stage(_ragged(5, 9))
-    assert B == 5 and toks.shape[0] > B  # bucketing did pad
+    toks, lens, valid, B, _ = dsb._stage(_ragged(5, 9))
+    assert B == 5 and lens.shape[0] > B  # bucketing did pad
     none_valid = np.zeros_like(np.asarray(valid))
     bits, flag = dsb._add_rt(dsb.bits, dsb.sharded.hasher, toks, lens,
                              none_valid)

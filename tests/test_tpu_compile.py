@@ -131,3 +131,35 @@ def test_wide_probe_split_compiles_on_four_chips(topo):
         h_sds, jax.ShapeDtypeStruct((B, N), U32, sharding=rows),
         jax.ShapeDtypeStruct((B,), jnp.int32, sharding=rows)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_grouped_admission_compiles_on_four_chips(topo):
+    """The dedup_docs_d4 filter's whole routed admission as a wave of its
+    mix is staged on a v5e host's four chips: two length-sorted row groups
+    cut from one token arena, one kernel each, then the (owner, local)
+    exchange, the test and the scatter. The 1.80 GB shard of bits is only
+    described."""
+    from repro.hash import ProbeTransport
+    from repro.hash.distributed import TokenArena, _admission_surfaces
+
+    mesh = make_mesh((4,), ("data",), devices=topo.devices)
+    m, k, D = 7_188_793_783, 9, 4
+    groups = ((3840, 2048), (256, 32768))
+    surfaces = _admission_surfaces(mesh, "data", m, ModPlan.for_modulus(m),
+                                   ProbeTransport())
+    h = Hasher.from_spec(HashSpec(n_hashes=k, out_bits=64,
+                                  variable_length=True), max_len=32768,
+                         plan=HashPlan(backend="pallas"))
+    rep, rows = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    h_sds = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep), h)
+    R, S = sum(b for b, _ in groups), sum(b * w for b, w in groups)
+    compiled = surfaces["_admit_rt"].lower(
+        jax.ShapeDtypeStruct((-(-m // D) * D,), jnp.uint8, sharding=rows),
+        h_sds,
+        TokenArena(jax.ShapeDtypeStruct((D, S), U32, sharding=rows), groups),
+        jax.ShapeDtypeStruct((D * R,), jnp.int32, sharding=rows),
+        jax.ShapeDtypeStruct((D * R,), jnp.bool_, sharding=rows)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == len(groups)
+    assert "all-to-all" in text
