@@ -47,7 +47,7 @@ _SMOKE_MODULES = "kernels,multihash,hasher,tree,distributed,gf"
 # hot-path rows gated by --max-regress: the COMPUTE-BOUND jit engine fast
 # paths whose regression would invalidate the paper-claim trajectory, plus
 # the routed-transport admission rows (the default transport's collective
-# layout is a headline claim; its hostmod/ingraph siblings stay advisory).
+# layout is a headline claim; its ingraph sibling stays advisory).
 # Other host-sync/collective-bound rows (distributed/*) and the interpret
 # Python-exec rows swing multi-x on shared-core CPU runners and stay in
 # the non-blocking report. Prefix match.

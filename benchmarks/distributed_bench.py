@@ -67,24 +67,20 @@ def _bench_meshes(meshes: "list[tuple[str, object]]") -> None:
             "host packed-word filter" if mesh is None else
             f"range-partitioned bits, one psum ({tag})", n_bytes=n_bytes)
 
-    # fused admission, one row per probe transport: 'hostmod' replays the
-    # legacy per-batch host round-trip (sync + (B, k) transfer to compute
-    # `h % m` in numpy), 'ingraph' the limbs.mod_u64 Barrett reduction +
-    # probe all_gather inside the launch, 'routed' the owner-bucketed
-    # all_to_all exchange (the default transport; its rows sit under the
-    # blocking regression gate, hence samples_us at a gate-grade repeat
-    # count -- 3 baseline + 6 fresh repeats cannot clear the permutation
-    # test's alpha=0.01)
-    derived = {"hostmod": "legacy host-side h%m round-trip",
-               "ingraph": "in-graph Barrett mod + probe all_gather",
+    # fused admission, one row per probe transport: 'ingraph' the
+    # limbs.mod_u64 Barrett reduction + probe all_gather inside the launch,
+    # 'routed' the owner-bucketed all_to_all exchange (the default
+    # transport; its rows sit under the blocking regression gate, hence
+    # samples_us at a gate-grade repeat count -- 3 baseline + 6 fresh
+    # repeats cannot clear the permutation test's alpha=0.01)
+    derived = {"ingraph": "in-graph Barrett mod + probe all_gather",
                "routed": "owner-bucketed probe all_to_all"}
-    transports = {"hostmod": "host", "ingraph": "all_gather",
-                  "routed": "routed"}
+    transports = {"ingraph": "all_gather", "routed": "routed"}
     admit_reps = 3 if fast else 7
     for tag, mesh in meshes:
         if mesh is None:
             continue
-        for mode in ("hostmod", "ingraph", "routed"):
+        for mode in ("ingraph", "routed"):
             dsb = DeviceShardedBloom(n_items=B, fp_rate=1e-3, mesh=mesh,
                                      probe_transport=transports[mode])
             fn = lambda dsb=dsb: dsb.check_and_add_batch(toks)  # noqa: E731

@@ -110,7 +110,7 @@ class ExactDedup:
 
     With `approx_items=N` the host set is replaced by a
     `DeviceShardedBloom` admission authority over `mesh` (default FP rate
-    1e-3, probes moved under `probe_transport` -- default "routed"): dedup
+    1e-3, its probes routed between devices): dedup
     for corpora whose exact fingerprint set won't fit host memory.
     Verdicts then carry Bloom semantics: a ~1e-3 false-duplicate rate, and
     in-batch duplicates ALL admit (pre-batch-state contract) instead of
@@ -118,8 +118,7 @@ class ExactDedup:
     """
 
     def __init__(self, seed: int = 0xDED0, backend: str | None = None,
-                 mesh=None, approx_items: int | None = None,
-                 probe_transport="routed"):
+                 mesh=None, approx_items: int | None = None):
         self.hasher = Hasher.from_spec(HashSpec(
             family="multilinear", n_hashes=1, out_bits=64,
             variable_length=True, seed=seed))
@@ -133,8 +132,7 @@ class ExactDedup:
             from ..hash.distributed import DeviceShardedBloom  # lazy: cycle
 
             self._bloom = DeviceShardedBloom(
-                n_items=int(approx_items), seed=seed ^ 0xB100, mesh=mesh,
-                probe_transport=probe_transport)
+                n_items=int(approx_items), seed=seed ^ 0xB100, mesh=mesh)
         self.seen: set[int] = set()
 
     def _fingerprints(self, items, backend=None) -> np.ndarray:

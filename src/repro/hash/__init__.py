@@ -16,8 +16,8 @@ functions remain as bit-identical deprecation shims over this package.
 """
 from . import distributed, faults, keyring, service, sharding, streaming, tree  # noqa: F401
 from .distributed import (  # noqa: F401
-    DeviceShardedBloom, FilterShardBackend, ProbeBucketOverflow,
-    ProbeTransport, ShardedHasher, bloom_shard_backends)
+    DeviceShardedBloom, FilterShardBackend, ShardedHasher,
+    bloom_shard_backends)
 from .faults import FaultEvent, FaultPlan, FaultyTransport  # noqa: F401
 from .hasher import Hasher, HashPlan, default_plan  # noqa: F401
 from .service import (  # noqa: F401

@@ -22,24 +22,21 @@ splits uniformly by construction):
   accounting uses the Lemire `(h*n)>>32` reduction from
   `repro.hash.sharding`.
 
-How probes move between devices is a first-class `ProbeTransport` spec
-(DESIGN.md section 7).  The default `"routed"` transport buckets each
+How probes move between devices is the filter's `probe_transport`, one
+of two kinds (DESIGN.md section 7).  The default `"routed"` buckets each
 device's (B/D, k) probe indices by owning bit range and exchanges ONLY the
-owned probes with one `jax.lax.all_to_all` (~1/D the bytes of the
-`"all_gather"` transport, which replicates the full (B, k) matrix);
-`"host"` replays the legacy per-batch host round-trip.  Every transport
-moves each probe as an (owner, local) int32 pair -- its shard and its
-index inside the shard -- so m may pass 2^32 while each shard stays
-below 2^31 bits.  All three are bit-identical to the single-device
-`BloomFilter` -- the transport moves the same global probe set, never
-changes it.  Collective layout: `add` is
-one fused launch with zero psums and ZERO host syncs; `contains` and the
-fused `check_and_add_batch` admission add exactly ONE psum.
+owned probes with one `jax.lax.all_to_all` (~1/D the bytes of
+`"all_gather"`, which replicates the full (B, k) matrix and is also the
+routed kind's overflow repair).  Both move each probe as an (owner,
+local) int32 pair -- its shard and its index inside the shard -- so m may
+pass 2^32 while each shard stays below 2^31 bits.  Both are bit-identical
+to the single-device `BloomFilter` -- the transport moves the same global
+probe set, never changes it.  Collective layout: `add` is one fused launch
+with zero psums and ZERO host syncs; `contains` and the fused
+`check_and_add_batch` admission add exactly ONE psum.
 """
 from __future__ import annotations
 
-import dataclasses
-import warnings
 from typing import NamedTuple
 
 import jax
@@ -137,85 +134,21 @@ class Staged(NamedTuple):
         return out[:self.n] if self.rows is None else out[self.rows]
 
 
-class ProbeBucketOverflow(RuntimeError):
-    """A routed probe exchange overflowed its static per-destination bucket
-    capacity (raised only under `ProbeTransport(on_overflow="error")`; the
-    default policy falls back to the all_gather transport instead).  The
-    filter state is ALWAYS repaired before this raises -- decisions already
-    returned and bits already set remain bit-identical to `BloomFilter`."""
+# routed bucket capacity: strong universality spreads a device's P probes
+# uniformly over the D owners, so a bucket expects P/D; the factor is the
+# headroom and the slack covers small P. Module constants, not options: a
+# test shrinks them to force the overflow repair.
+_CAPACITY_FACTOR = 1.25
+_CAPACITY_SLACK = 16
 
 
-@dataclasses.dataclass(frozen=True)
-class ProbeTransport:
-    """How `DeviceShardedBloom` moves probes between devices. A probe is
-    an (owner, local) int32 pair on every transport: the shard that holds
-    its bit and the bit's index inside that shard.
-
-    kinds (all three bit-identical to the single-device `BloomFilter`):
-      "routed"      default -- bucket each device's (B/D, k) probes by
-                    owner and exchange ONLY (local, row) pairs with one
-                    `jax.lax.all_to_all` (~capacity_factor/D the bytes of
-                    all_gather); per-item verdicts come back via ONE psum of
-                    per-row miss counts keyed by global row id.
-      "all_gather"  replicate the full (B, k) pair matrix to every device
-                    (what `in_graph_mod=True` meant).
-      "host"        legacy per-batch host round-trip: hash_batch -> numpy
-                    `h % m` -> (owner, local) pairs -> replicated operand
-                    (`in_graph_mod=False`).
-
-    Bucket capacity is static (jit needs fixed shapes): each destination
-    receives at most `capacity(P, D)` of a device's P = (B/D)*k probes.
-    Strong universality spreads probes uniformly over owners, so the
-    expected load is P/D and `capacity_factor` is the safety headroom; the
-    tail risk is handled, not ignored -- overflow is detected in-graph
-    (truncated probes raise a per-device flag) and `on_overflow` picks the
-    recovery: "fallback" replays the batch through the all_gather surface
-    (bit-identical, counted in `stats["overflow_fallbacks"]`), "error"
-    repairs the filter the same way and then raises `ProbeBucketOverflow`.
-    """
-
-    kind: str = "routed"
-    capacity_factor: float = 1.25
-    capacity_slack: int = 16
-    on_overflow: str = "fallback"
-
-    _KINDS = ("host", "all_gather", "routed")
-
-    def __post_init__(self):
-        if self.kind not in self._KINDS:
-            raise ValueError(
-                f"probe_transport kind {self.kind!r} not in {self._KINDS}")
-        if self.on_overflow not in ("fallback", "error"):
-            raise ValueError(
-                f"on_overflow {self.on_overflow!r} not in "
-                "('fallback', 'error')")
-        if not (self.capacity_factor > 0):
-            raise ValueError("capacity_factor must be > 0")
-        if self.capacity_slack < 0:
-            raise ValueError("capacity_slack must be >= 0")
-
-    @classmethod
-    def of(cls, value) -> "ProbeTransport":
-        """Resolve the constructor spec: a kind string or an instance."""
-        if isinstance(value, cls):
-            return value
-        if isinstance(value, str):
-            return cls(kind=value)
-        raise TypeError(
-            f"probe_transport must be a str or ProbeTransport, got "
-            f"{type(value).__name__}")
-
-    def capacity(self, n_probes: int, n_devices: int) -> int:
-        """Static per-destination bucket capacity for a device's `n_probes`
-        probes over `n_devices` owners. Clamped to n_probes (a bucket can
-        never need more), so with the default factor >= 1 a 1-device mesh
-        is structurally overflow-free; a deliberately tiny factor can still
-        overflow anywhere -- that is the chaos-test knob."""
-        cap = -(-int(n_probes * self.capacity_factor) // n_devices)
-        return max(1, min(int(n_probes), cap + self.capacity_slack))
-
-
-_UNSET = object()  # sentinel: distinguishes in_graph_mod=absent from =True
+def _capacity(n_probes: int, D: int) -> int:
+    """Static per-destination bucket capacity of the routed exchange for a
+    device's `n_probes` probes over D owners (jit needs fixed shapes).
+    Clamped to n_probes (a bucket can never need more), so a 1-device mesh
+    is structurally overflow-free."""
+    cap = -(-int(n_probes * _CAPACITY_FACTOR) // D)
+    return max(1, min(int(n_probes), cap + _CAPACITY_SLACK))
 
 
 class ShardedHasher:
@@ -450,15 +383,13 @@ def probe_owner_local(r, m: int, m_local: int, n_shards: int):
 
 
 def _admission_surfaces(mesh: Mesh, axis: str, m: int,
-                        plan: "limbs.ModPlan",
-                        transport: ProbeTransport) -> dict:
+                        plan: "limbs.ModPlan") -> dict:
     """The jitted `shard_map` surfaces of a `DeviceShardedBloom` of m bits
-    over `mesh`'s `axis`, by attribute name: `_add`, `_contains` and
-    `_admit` take (bits, (B, k, 2) pairs) from the host transport; the
-    in-graph `_add_dev`, `_contains_dev`, `_admit_dev` (all_gather) and
-    `_add_rt`, `_contains_rt`, `_admit_rt` (routed) take (bits, hasher,
-    `TokenArena`, lengths, valid). Built apart from a filter, so a surface
-    compiles for a described topology without allocating any bits."""
+    over `mesh`'s `axis`, by attribute name: `_add_dev`, `_contains_dev`,
+    `_admit_dev` (all_gather) and `_add_rt`, `_contains_rt`, `_admit_rt`
+    (routed), each taking (bits, hasher, `TokenArena`, lengths, valid).
+    Built apart from a filter, so a surface compiles for a described
+    topology without allocating any bits."""
     D = mesh_axis_size(mesh, axis)
     m_local, ax = -(-m // D), axis
 
@@ -475,6 +406,22 @@ def _admission_surfaces(mesh: Mesh, axis: str, m: int,
             m, m_local, D)
         return jnp.where(valid[:, None], owner, I32(D)), local
 
+    def _scatter(bits, local):
+        """Set every bit at `local`; the drop index m_local (foreign,
+        sentinel or unused probes) falls outside the shard
+        (mode="drop")."""
+        return bits.at[local.ravel()].set(U8(1), mode="drop")
+
+    # -- all_gather transport: every device sees every (owner, local) pair
+
+    def _gather(hs, toks, lens, valid):
+        """A device's staged rows -> (B, k, 2) int32 (owner, local) pairs of
+        every row: one all_gather of each device's pairs along the data
+        axis."""
+        return jax.lax.all_gather(
+            jnp.stack(_pairs(hs, toks, lens, valid), axis=-1), ax,
+            axis=0, tiled=True)
+
     def _local(p):
         """(..., 2) (owner, local) pairs -> (local index, owned mask)
         with other devices' probes sent to the drop slot m_local."""
@@ -488,34 +435,18 @@ def _admission_surfaces(mesh: Mesh, axis: str, m: int,
         return jax.lax.psum(
             jnp.sum((probe == 0).astype(I32), axis=1), ax)
 
-    def _pairs_in_graph(hs, toks, lens, valid):
-        """A device's staged rows -> (B, k, 2) int32 (owner, local) pairs of
-        every row: one all_gather of each device's pairs along the data
-        axis (the device-to-device twin of the old host round-trip)."""
-        return jax.lax.all_gather(
-            jnp.stack(_pairs(hs, toks, lens, valid), axis=-1), ax,
-            axis=0, tiled=True)
-
-    def add_body(bits, p):
-        loc, _ = _local(p)
-        return bits.at[loc.ravel()].set(U8(1), mode="drop")
-
-    def contains_body(bits, p):
-        return _miss(bits, p) == 0
-
-    def admit_body(bits, p):
-        present = _miss(bits, p) == 0
-        loc, _ = _local(p)
-        return bits.at[loc.ravel()].set(U8(1), mode="drop"), ~present
-
     def add_body_dev(bits, hs, toks, lens, valid):
-        return add_body(bits, _pairs_in_graph(hs, toks, lens, valid))
+        loc, _ = _local(_gather(hs, toks, lens, valid))
+        return _scatter(bits, loc)
 
     def contains_body_dev(bits, hs, toks, lens, valid):
-        return contains_body(bits, _pairs_in_graph(hs, toks, lens, valid))
+        return _miss(bits, _gather(hs, toks, lens, valid)) == 0
 
     def admit_body_dev(bits, hs, toks, lens, valid):
-        return admit_body(bits, _pairs_in_graph(hs, toks, lens, valid))
+        p = _gather(hs, toks, lens, valid)
+        present = _miss(bits, p) == 0
+        loc, _ = _local(p)
+        return _scatter(bits, loc), ~present
 
     # -- routed transport: owner-bucketed all_to_all probe exchange ----
 
@@ -545,7 +476,7 @@ def _admission_surfaces(mesh: Mesh, axis: str, m: int,
         owner, local = _pairs(hs, toks, lens, valid)
         b, k = owner.shape
         n_probes = b * k
-        cap = transport.capacity(n_probes, D)
+        cap = _capacity(n_probes, D)
         dest = owner.reshape(n_probes)
         onehot = dest[None, :] == jnp.arange(D, dtype=I32)[:, None]
         pos = jnp.cumsum(onehot.astype(I32), axis=1)  # (D, n) running
@@ -561,11 +492,6 @@ def _admission_surfaces(mesh: Mesh, axis: str, m: int,
             jnp.stack([sl, sr], axis=-1), ax,
             split_axis=0, concat_axis=0, tiled=True)
         return recv[..., 0], recv[..., 1], overflow, b
-
-    def _scatter_rt(bits, recv_local):
-        """Set every received bit; the unused slots' drop index m_local
-        falls outside the shard (mode="drop")."""
-        return bits.at[recv_local.ravel()].set(U8(1), mode="drop")
 
     def _miss_rt(bits, recv_local, recv_row, b):
         """(Bp,) global miss counts: test received probes locally,
@@ -596,7 +522,7 @@ def _admission_surfaces(mesh: Mesh, axis: str, m: int,
 
     def add_body_rt(bits, hs, toks, lens, valid):
         recv_l, _, overflow, _ = _route(hs, toks, lens, valid)
-        return _scatter_rt(bits, recv_l), overflow[None]
+        return _scatter(bits, recv_l), overflow[None]
 
     def contains_body_rt(bits, hs, toks, lens, valid):
         recv_l, recv_row, overflow, b = _route(hs, toks, lens, valid)
@@ -606,32 +532,25 @@ def _admission_surfaces(mesh: Mesh, axis: str, m: int,
     def admit_body_rt(bits, hs, toks, lens, valid):
         recv_l, recv_row, overflow, b = _route(hs, toks, lens, valid)
         present = _miss_rt(bits, recv_l, recv_row, b) == 0
-        return _scatter_rt(bits, recv_l), ~present, overflow[None]
+        return _scatter(bits, recv_l), ~present, overflow[None]
 
+    # the hasher rides as a replicated pytree operand (like ShardedHasher),
+    # tokens/lengths/valid partition over the axis
     sm = lambda body, out_specs: jax.jit(jax.shard_map(  # noqa: E731
-        body, mesh=mesh, in_specs=(P(ax), P()),
-        out_specs=out_specs, check_vma=False))
-    # in-graph surfaces: the hasher rides as a replicated pytree operand
-    # (like ShardedHasher), tokens/lengths/valid partition over the axis
-    smg = lambda body, out_specs: jax.jit(jax.shard_map(  # noqa: E731
         body, mesh=mesh,
         in_specs=(P(ax), P(), P(ax), P(ax), P(ax)),
         out_specs=out_specs, check_vma=False))
     return {
-        "_add": sm(add_body, P(ax)),
-        "_contains": sm(contains_body, P()),
-        "_admit": sm(admit_body, (P(ax), P())),
-        "_add_dev": smg(add_body_dev, P(ax)),
-        "_contains_dev": smg(contains_body_dev, P()),
-        "_admit_dev": smg(admit_body_dev, (P(ax), P())),
+        "_add_dev": sm(add_body_dev, P(ax)),
+        "_contains_dev": sm(contains_body_dev, P()),
+        "_admit_dev": sm(admit_body_dev, (P(ax), P())),
         # routed surfaces: same operand layout; overflow flags come back
         # per-device (out_spec P(axis) over a (1,) bool) so reading them
         # never adds a collective to the launch.
-        "_add_rt": smg(add_body_rt, (P(ax), P(ax))),
-        "_contains_rt": smg(contains_body_rt, (P(), P(ax))),
-        "_admit_rt": smg(admit_body_rt, (P(ax), P(), P(ax))),
+        "_add_rt": sm(add_body_rt, (P(ax), P(ax))),
+        "_contains_rt": sm(contains_body_rt, (P(), P(ax))),
+        "_admit_rt": sm(admit_body_rt, (P(ax), P(), P(ax))),
     }
-
 
 
 class DeviceShardedBloom:
@@ -653,12 +572,11 @@ class DeviceShardedBloom:
     reduction (`limbs.mod_u64`, exact for every m < 2^63, as two limbs past
     2^32 -- DESIGN.md §2), then splits each residue into an (owner, local)
     int32 pair (`probe_owner_local`). How the pairs reach the devices that
-    OWN those bits is the `probe_transport` spec (`ProbeTransport`): the
-    default `"routed"` transport buckets them by owner and exchanges only
-    (local, row) pairs with one `jax.lax.all_to_all`; `"all_gather"`
-    replicates the full (B, k) pair matrix; `"host"` replays the legacy
-    per-batch host round-trip. A receiver sets and tests `local` as it is.
-    Admission never leaves the device on either in-graph transport.
+    OWN those bits is `probe_transport`: the default `"routed"` buckets
+    them by owner and exchanges only (local, row) pairs with one
+    `jax.lax.all_to_all`; `"all_gather"` replicates the full (B, k) pair
+    matrix. Any other value raises `ValueError`. A receiver sets and tests
+    `local` as it is. Admission never leaves the device on either kind.
 
     Collective layout (per-transport bytes table in DESIGN.md §7):
       add_batch             one launch, one collective, ZERO psums and ZERO
@@ -673,38 +591,34 @@ class DeviceShardedBloom:
     `(h*n)>>32` reduction from `repro.hash.sharding` for multi-host admission
     planning; probe ownership itself is the contiguous range map above.
 
-    Routed bucket overflow (static capacity, see `ProbeTransport`): add
+    Routed bucket overflow: each bucket holds `_capacity` probes, a static
+    shape, so a bucket past it truncates and raises an in-graph flag. The
+    one policy is repair by replay through the all_gather surface. Add
     launches stay zero-sync by deferring the flag read -- the batch is
     queued and the flags of up to `_settle_every` pending adds materialize
     together at the next verdict-returning call (or `bits` read). Truncated
-    scatters only ever light a SUBSET of the correct bits, so recovery is a
-    replay of the overflowed batches through the all_gather surface: bit
-    union makes the repair exact, no snapshot needed.
+    scatters only ever light a SUBSET of the correct bits, so bit union
+    makes the replay exact, no snapshot needed. A verdict-returning call
+    reads its flag with its verdicts and reruns against the untouched
+    pre-call bits.
 
-    `in_graph_mod=` is DEPRECATED (one-warning shim): True meant
-    `probe_transport="all_gather"`, False the `"host"` round-trip -- the
-    latter kept as the decision-identity A/B reference and bench baseline;
-    every transport is bit-identical to the single-device `BloomFilter` by
-    construction.
-
-    `stats` is the operator's view of the in-graph paths, plain ints since
-    construction. `overflow_fallbacks`: routed batches replayed through
+    `stats` is the operator's view of the filter, plain ints since
+    construction. `overflow_fallbacks`: routed calls replayed through
     all_gather. Counted per staged call of `add_batch`, `contains_batch`
-    and `check_and_add_batch` (the legacy `"host"` transport stages
-    nothing): `calls`; `items`, the real rows B; `real_tokens`, the sum of
-    the item lengths; `staged_tokens`, the slots of the call's row groups
-    (rows x width, summed); `stage_groups`, the groups it launched (1
-    where the call stays one bucket, else 2). `staged_tokens / real_tokens` is the
-    padding waste: the factor by which the staged, copied and hashed bytes
-    exceed the input. `stage_reuses`: staged calls written into the kept
-    arena; `stage_allocs`: staged calls that had to allocate one (the
-    first call, and every change of the groups' shapes). Counted per
-    in-graph launch, from shapes alone (no sync): `exchange_slots`, the
-    slots of one device's routed send buffer (D x capacity, routed
-    launches only), and `exchange_bytes`, the bytes of one device's send
-    buffer on every in-graph launch, replays included (8 a slot or pair:
-    routed, D x capacity (local, row) slots; all_gather, its (B/D) x k
-    pairs).
+    and `check_and_add_batch`: `calls`; `items`, the real rows B;
+    `real_tokens`, the sum of the item lengths; `staged_tokens`, the slots
+    of the call's row groups (rows x width, summed); `stage_groups`, the
+    groups it launched (1 where the call stays one bucket, else 2).
+    `staged_tokens / real_tokens` is the padding waste: the factor by
+    which the staged, copied and hashed bytes exceed the input.
+    `stage_reuses`: staged calls written into the kept arena;
+    `stage_allocs`: staged calls that had to allocate one (the first call,
+    and every change of the groups' shapes). Counted per launch, from
+    shapes alone (no sync): `exchange_slots`, the slots of one device's
+    routed send buffer (D x capacity, routed launches only), and
+    `exchange_bytes`, the bytes of one device's send buffer on every
+    launch, replays included (8 a slot or pair: routed, D x capacity
+    (local, row) slots; all_gather, its (B/D) x k pairs).
 
     Host staging (`_stage`) cuts a call's length-sorted rows in two. Rows
     bucket to Bp = D x pow2(ceil(B/D)), as `_bucket_shape` gives; a stable
@@ -757,19 +671,15 @@ class DeviceShardedBloom:
 
     def __init__(self, n_items: int, fp_rate: float = 1e-3, seed: int = 0xB100,
                  mesh: Mesh | None = None, axis: str = "data",
-                 in_graph_mod=_UNSET,
-                 probe_transport: "ProbeTransport | str" = "routed",
+                 probe_transport: str = "routed",
                  family: str = "multilinear"):
         import math
 
-        if in_graph_mod is not _UNSET:
-            warnings.warn(
-                "DeviceShardedBloom(in_graph_mod=...) is deprecated; pass "
-                "probe_transport='all_gather' (was True) or 'host' (was "
-                "False) -- see repro.hash.distributed.ProbeTransport",
-                DeprecationWarning, stacklevel=2)
-            probe_transport = "all_gather" if in_graph_mod else "host"
-        self.transport = ProbeTransport.of(probe_transport)
+        if probe_transport not in ("routed", "all_gather"):
+            raise ValueError(
+                f"probe_transport {probe_transport!r} is neither 'routed' "
+                "nor 'all_gather'")
+        self.transport = probe_transport
 
         # same sizing as data.dedup.BloomFilter -- decision identity needs
         # identical (m, k) for identical inputs
@@ -784,9 +694,8 @@ class DeviceShardedBloom:
                 "shard, past the int32 local index (2^31): shard the filter "
                 "over more devices; packed bits (32 to an int32 word) would "
                 "lift the limit")
-        # any engine family works: probes are `h % m` on the family's
-        # 64-bit hash_batch surface on every path (host round-trip and the
-        # fused in-graph mod_m epilogue agree per family by construction)
+        # any engine family works: probes are `h % m` of the family's 64-bit
+        # hash, taken in-graph by the fused mod_m epilogue
         self.sharded = ShardedHasher(Hasher.from_spec(HashSpec(
             family=family, n_hashes=self.k, out_bits=64,
             variable_length=True, seed=seed)), self.mesh, axis)
@@ -809,17 +718,11 @@ class DeviceShardedBloom:
         self._buf_rows = self._buf_lens = self._buf_fence = None
 
         vars(self).update(_admission_surfaces(
-            self.mesh, axis, self.m, self.plan, self.transport))
+            self.mesh, axis, self.m, self.plan))
 
     @property
     def n_shards(self) -> int:
         return self.sharded.n_shards
-
-    @property
-    def in_graph_mod(self) -> bool:
-        """Deprecated read-only view of the old boolean flag: True for any
-        in-graph transport, False only for the legacy host round-trip."""
-        return self.transport.kind != "host"
 
     @property
     def bits(self) -> jnp.ndarray:
@@ -835,8 +738,7 @@ class DeviceShardedBloom:
         whose flag fired were truncated -- their scatters lit a SUBSET of
         the correct bits -- so replay exactly those through the all_gather
         surface (bit union repairs in place; adds already fully applied are
-        untouched). Under `on_overflow="error"` the repair still runs, then
-        the typed error surfaces the capacity misconfiguration."""
+        untouched)."""
         if not self._pending:
             return
         pending, self._pending = self._pending, []
@@ -844,32 +746,24 @@ class DeviceShardedBloom:
                   if bool(np.asarray(flag).any())]
         if not replay:
             return
-        self.stats["overflow_fallbacks"] += len(replay)
         with _span("bloom.overflow_replay"):
             for st in replay:
-                self._count_exchange(st.lens.shape[0], routed=False)
-                self._bits = self._add_dev(
-                    self._bits, self.sharded.hasher, *st[:3])
-        if self.transport.on_overflow == "error":
-            raise ProbeBucketOverflow(
-                f"{len(replay)} routed add batch(es) overflowed the static "
-                f"bucket capacity (capacity_factor="
-                f"{self.transport.capacity_factor}); state repaired via "
-                "all_gather replay -- raise capacity_factor/capacity_slack "
-                "or use probe_transport='all_gather'")
+                self._bits = self._replay(self._add_dev, st)
 
-    def _probes(self, items) -> np.ndarray:
-        """LEGACY host round-trip path (`probe_transport="host"`): (B, k, 2)
-        int32 (owner, local) probe pairs -- the full 64-bit accumulators mod
-        m, exactly the single-device `BloomFilter` formula, hashed B/D rows
-        per device then reduced with numpy's `%` and split by shard on the
-        host. Bit-identical to the in-graph Barrett reduction and
-        `probe_owner_local`; kept as the A/B reference and bench baseline."""
-        r = (self.sharded.hash_batch(items) % np.uint64(self.m)).astype(
-            np.int64)  # (B, k)
-        owner = r // self.m_local
-        return np.stack([owner, r - owner * self.m_local],
-                        axis=-1).astype(np.int32)
+    def _prepare(self, items):
+        """Stage `items` and count the launch they are staged for: the
+        `Staged` call and the surface arguments after the bits."""
+        st = self._stage(items)
+        self._count_exchange(st.lens.shape[0], self.transport == "routed")
+        return st, (self.sharded.hasher, *st[:3])
+
+    def _replay(self, surface, st: Staged):
+        """Rerun a routed call whose exchange overflowed through its
+        all_gather `surface`, against the filter's current bits, and count
+        it."""
+        self.stats["overflow_fallbacks"] += 1
+        self._count_exchange(st.lens.shape[0], routed=False)
+        return surface(self._bits, self.sharded.hasher, *st[:3])
 
     def _count_exchange(self, rows: int, routed: bool) -> None:
         """Add one in-graph launch over `rows` staged rows to the exchange
@@ -877,7 +771,7 @@ class DeviceShardedBloom:
         D = self.n_shards
         n = rows // D * self.k  # one device's probes
         if routed:
-            slots = D * self.transport.capacity(n, D)
+            slots = D * _capacity(n, D)
             self.stats["exchange_slots"] += slots
             self.stats["exchange_bytes"] += slots * 8
         else:
@@ -920,7 +814,7 @@ class DeviceShardedBloom:
         return np.concatenate(out)
 
     def _stage(self, items) -> Staged:
-        """Stage host items for the in-graph path (class docstring). Rows
+        """Stage host items for a launch (class docstring). Rows
         bucket to `_bucket_shape`'s pow2 row count Bp; `_groups` either
         keeps them in order in one Bp x Np bucket, or orders them by
         length and cuts them into two groups, each as wide as the power of
@@ -1036,19 +930,11 @@ class DeviceShardedBloom:
         """Admit a batch in ONE fused launch: hash + Barrett mod + probe
         exchange + owned-range scatter, all in-graph -- zero psums and
         ZERO host syncs (the routed overflow flag is deferred to the next
-        settle point; the legacy host transport instead syncs on
-        `_probes`)."""
+        settle point)."""
         if len(items) == 0:
             return
-        kind = self.transport.kind
-        if kind == "host":
-            self._bits = self._add(
-                self._bits, jnp.asarray(self._probes(items)))
-            return
-        st = self._stage(items)
-        self._count_exchange(st.lens.shape[0], routed=kind == "routed")
-        args = (self.sharded.hasher, *st[:3])
-        if kind == "all_gather":
+        st, args = self._prepare(items)
+        if self.transport == "all_gather":
             self._bits = self._add_dev(self._bits, *args)
             return
         self._bits, flag = self._add_rt(self._bits, *args)
@@ -1062,21 +948,13 @@ class DeviceShardedBloom:
         routed overflow flag rides in the same transfer)."""
         if len(items) == 0:
             return np.zeros(0, bool)
-        kind = self.transport.kind
-        if kind == "host":
-            return np.asarray(
-                self._contains(self._bits, jnp.asarray(self._probes(items))))
         self._settle()
-        st = self._stage(items)
-        self._count_exchange(st.lens.shape[0], routed=kind == "routed")
-        args = (self.sharded.hasher, *st[:3])
-        if kind == "all_gather":
+        st, args = self._prepare(items)
+        if self.transport == "all_gather":
             return st.pick(self._contains_dev(self._bits, *args))
         verdict, flag = self._contains_rt(self._bits, *args)
         if bool(np.asarray(flag).any()):
-            self._overflowed("contains_batch")
-            self._count_exchange(st.lens.shape[0], routed=False)
-            verdict = self._contains_dev(self._bits, *args)
+            verdict = self._replay(self._contains_dev, st)
         return st.pick(verdict)
 
     def check_and_add_batch(self, items) -> np.ndarray:
@@ -1087,51 +965,33 @@ class DeviceShardedBloom:
         sub-batch when arrival-order dedup inside a batch matters)."""
         if len(items) == 0:
             return np.zeros(0, bool)
-        kind = self.transport.kind
-        if kind == "host":
-            self._bits, admitted = self._admit(
-                self._bits, jnp.asarray(self._probes(items)))
-            return np.asarray(admitted)
         with _span("bloom.check_and_add_batch") as span:
             self._settle()
-            st = self._stage(items)
+            st, args = self._prepare(items)
             groups = st.tokens.groups
             span.set_metadata(call=self.stats["calls"], items=st.n,
                               width=max(w for _, w in groups),
                               groups=len(groups), shards=self.n_shards,
                               m_bits=self.m)
-            self._count_exchange(st.lens.shape[0], routed=kind == "routed")
-            args = (self.sharded.hasher, *st[:3])
-            if kind == "all_gather":
-                with _span("bloom.launch"):
-                    self._bits, admitted = self._admit_dev(self._bits, *args)
-                with _span("bloom.readback"):
-                    return st.pick(admitted)
+            routed = self.transport == "routed"
             with _span("bloom.launch"):
-                new_bits, admitted, flag = self._admit_rt(self._bits, *args)
+                if routed:
+                    new_bits, admitted, flag = self._admit_rt(
+                        self._bits, *args)
+                else:
+                    new_bits, admitted = self._admit_dev(self._bits, *args)
             with _span("bloom.readback"):
-                overflow = bool(np.asarray(flag).any())
+                overflow = routed and bool(np.asarray(flag).any())
                 if not overflow:
                     admitted = st.pick(admitted)
             if overflow:
                 # truncated exchange: discard the partial scatter/verdicts
                 # and rerun against the untouched pre-call bits via all_gather
                 with _span("bloom.overflow_replay"):
-                    self._overflowed("check_and_add_batch")
-                    self._count_exchange(st.lens.shape[0], routed=False)
-                    new_bits, admitted = self._admit_dev(self._bits, *args)
+                    new_bits, admitted = self._replay(self._admit_dev, st)
                     admitted = st.pick(admitted)
             self._bits = new_bits
             return admitted
-
-    def _overflowed(self, op: str) -> None:
-        self.stats["overflow_fallbacks"] += 1
-        if self.transport.on_overflow == "error":
-            raise ProbeBucketOverflow(
-                f"routed {op} overflowed the static bucket capacity "
-                f"(capacity_factor={self.transport.capacity_factor}); the "
-                "filter state is unchanged -- raise capacity_factor/"
-                "capacity_slack or use probe_transport='all_gather'")
 
     def add(self, item) -> None:
         self.add_batch([np.atleast_1d(item)])
@@ -1198,23 +1058,20 @@ class FilterShardBackend:
 def bloom_shard_backends(
         n_shards: int, n_items: int, fp_rate: float = 1e-3,
         seed: int = 0xB100, *, mesh: Mesh | None = None,
-        probe_transport: "ProbeTransport | str" = "routed",
 ) -> "list[FilterShardBackend]":
     """`n_shards` keyspace-partitioned Bloom backends for the admission
     service (each shard's filter sized for its 1/n share of the items; the
     service's Lemire routing keeps loads uniform by strong universality).
 
     With `mesh=` each shard's filter is a `DeviceShardedBloom` whose bits
-    range-partition over the mesh data axis under the given
-    `probe_transport` (default "routed"); verdicts are then against the
+    range-partition over the mesh data axis; verdicts are then against the
     pre-batch state (the batched contract) instead of the host filter's
     arrival order -- the service's per-shard batching makes both orders
     converge to the same filter state."""
     per = max(1, -(-int(n_items) // int(n_shards)))
     if mesh is not None:
         return [FilterShardBackend(DeviceShardedBloom(
-                    n_items=per, fp_rate=fp_rate, seed=seed, mesh=mesh,
-                    probe_transport=probe_transport))
+                    n_items=per, fp_rate=fp_rate, seed=seed, mesh=mesh))
                 for _ in range(int(n_shards))]
     from ..data.dedup import BloomFilter
 

@@ -356,20 +356,18 @@ class AdmissionService:
     @classmethod
     def over_bloom_shards(cls, n_shards: int, n_items: int, *,
                           fp_rate: float = 1e-3, shard_seed: int = 0xB100,
-                          mesh=None, probe_transport="routed",
-                          **kwargs) -> "AdmissionService":
+                          mesh=None, **kwargs) -> "AdmissionService":
         """Service over `n_shards` in-process Bloom backends in one call.
 
         With `mesh=` every shard's L2 filter is a `DeviceShardedBloom`
-        range-partitioned over the mesh data axis, moving probes under
-        `probe_transport` (default "routed": one all_to_all of owned probes
-        per call -- `repro.hash.distributed.ProbeTransport`). Remaining
-        kwargs go to the service constructor (policy/retry/clock/...)."""
+        range-partitioned over the mesh data axis, moving probes by its
+        default routed exchange (one all_to_all of owned probes per call).
+        Remaining kwargs go to the service constructor
+        (policy/retry/clock/...)."""
         from .distributed import bloom_shard_backends  # lazy: import cycle
 
         backends = bloom_shard_backends(
-            n_shards, n_items, fp_rate=fp_rate, seed=shard_seed, mesh=mesh,
-            probe_transport=probe_transport)
+            n_shards, n_items, fp_rate=fp_rate, seed=shard_seed, mesh=mesh)
         return cls(InProcessTransport(backends), **kwargs)
 
     # -- small helpers -------------------------------------------------------

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..hash import Hasher, HashSpec, ProbeBucketOverflow
+from ..hash import Hasher, HashSpec
 from ..hash.service import TransportError
 
 _PREFIX_KEY_SEED = 0x1E53
@@ -39,7 +39,6 @@ class ServeEngine:
     def __init__(self, api, params, *, n_slots: int = 4, max_seq: int = 256,
                  greedy: bool = True, mesh=None, admission=None,
                  admission_items: int | None = None,
-                 probe_transport="routed",
                  tree_prompt_words: int = 1 << 12):
         self.api = api
         self.params = params
@@ -75,16 +74,15 @@ class ServeEngine:
         # prompts are rejected before they cost a prefill; the engine keeps
         # serving through backend outages (DESIGN.md §8). `admission_items=`
         # builds one in-process: a single L2 shard whose filter is a
-        # DeviceShardedBloom over the engine's mesh, probes moved under
-        # `probe_transport` (default "routed" -- one all_to_all per wave).
+        # DeviceShardedBloom over the engine's mesh (its probes routed: one
+        # all_to_all per wave).
         if admission is None and admission_items is not None:
             from ..hash.service import AdmissionService
             from ..parallel.sharding import data_mesh
 
             admission = AdmissionService.over_bloom_shards(
                 1, int(admission_items),
-                mesh=data_mesh() if mesh is None else mesh,
-                probe_transport=probe_transport)
+                mesh=data_mesh() if mesh is None else mesh)
         self.admission = admission
         self.stats = {"prefix_hits": 0, "prefills": 0, "ticks": 0,
                       "degraded_ticks": 0, "l1_only_admits": 0,
@@ -166,10 +164,10 @@ class ServeEngine:
         the `AdmissionService` (L1/L2 filters + retry/breaker). Called with
         the NEXT wave while the current decode step is still in flight, so
         L2 round-trips overlap device compute. An admission outage the
-        service itself could not absorb (its own transport or probe-bucket
-        errors) falls back to serving everything -- the engine's job is to
-        answer requests. Any other error (a kernel that fails to lower, a
-        bad argument) propagates: it is a bug, not an outage."""
+        service itself could not absorb (its own transport errors) falls
+        back to serving everything -- the engine's job is to answer
+        requests. Any other error (a kernel that fails to lower, a bad
+        argument) propagates: it is a bug, not an outage."""
         if self.admission is None:
             return
         todo = [r for r in reqs if r.admitted is None]
@@ -178,7 +176,7 @@ class ServeEngine:
         try:
             mask = self.admission.admit_batch(
                 [r.prompt.astype(np.uint32) for r in todo])
-        except (TransportError, ProbeBucketOverflow):
+        except TransportError:
             self.stats["admission_errors"] += 1
             for r in todo:
                 r.admitted = True

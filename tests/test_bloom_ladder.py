@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from repro.data.dedup import BloomFilter
-from repro.hash import DeviceShardedBloom, ProbeTransport
+from repro.hash import DeviceShardedBloom
 from repro.hash import distributed
 from repro.hash.distributed import _ladder
 from repro.parallel.sharding import data_mesh
@@ -29,9 +29,9 @@ FP = 0.05
 TRANSPORTS = {
     "routed": "routed",
     "all_gather": "all_gather",
-    # every routed launch overflows: each call replays through all_gather
-    "routed_overflow": ProbeTransport("routed", capacity_factor=1e-9,
-                                      capacity_slack=0),
+    # one probe a routed bucket (the test shrinks the capacity constants):
+    # every routed launch overflows, so each call replays through all_gather
+    "routed_overflow": "routed",
 }
 
 
@@ -89,6 +89,9 @@ def _one_bucket(monkeypatch, fn, *args):
 def test_grouped_admission_matches_one_bucket_and_the_host_filter(
         transport, monkeypatch):
     tp = TRANSPORTS[transport]
+    if transport == "routed_overflow":
+        monkeypatch.setattr(distributed, "_CAPACITY_FACTOR", 1e-9)
+        monkeypatch.setattr(distributed, "_CAPACITY_SLACK", 0)
     grouped = DeviceShardedBloom(n_items=3000, fp_rate=FP,
                                  mesh=data_mesh(1), probe_transport=tp)
     bucket = DeviceShardedBloom(n_items=3000, fp_rate=FP,
@@ -284,7 +287,7 @@ def test_grouped_admission_on_four_devices():
     code = """
         import numpy as np
         from repro.data.dedup import BloomFilter
-        from repro.hash import DeviceShardedBloom, ProbeTransport
+        from repro.hash import DeviceShardedBloom
         from repro.hash import distributed
         from repro.parallel.sharding import data_mesh
         rng = np.random.default_rng(0xD4)
@@ -293,17 +296,18 @@ def test_grouped_admission_on_four_devices():
                     .astype(np.uint32) for n in lengths]
         ladder = distributed._ladder
         one = lambda Bp, D: ()
-        tiny = ProbeTransport("routed", capacity_factor=1e-9,
-                              capacity_slack=0)
         # every wave: these lengths, 20 slots repeating the last wave's
         lens = [0, 1, 2, 31, 32, 33, 127, 128, 129] + list(
             np.maximum(np.rint(9 * np.exp(rng.standard_normal(290))),
                        1).astype(int))
-        for tp in ("routed", tiny):
+        for overflow in (False, True):
+            if overflow:  # one probe a routed bucket: every launch replays
+                distributed._CAPACITY_FACTOR = 1e-9
+                distributed._CAPACITY_SLACK = 0
             g = DeviceShardedBloom(n_items=4000, fp_rate=0.05,
-                                   mesh=data_mesh(4), probe_transport=tp)
+                                   mesh=data_mesh(4))
             b = DeviceShardedBloom(n_items=4000, fp_rate=0.05,
-                                   mesh=data_mesh(4), probe_transport=tp)
+                                   mesh=data_mesh(4))
             b.sharded = g.sharded  # one hasher and one set of compiles
             for name in ("_add_dev", "_contains_dev", "_admit_dev",
                          "_add_rt", "_contains_rt", "_admit_rt"):
@@ -341,7 +345,7 @@ def test_grouped_admission_on_four_devices():
                                               np.asarray(b.bits))
             assert g.stats["stage_groups"] > g.stats["calls"]
             assert b.stats["stage_groups"] == b.stats["calls"]
-            assert (g.stats["overflow_fallbacks"] > 0) == (tp is tiny)
+            assert (g.stats["overflow_fallbacks"] > 0) == overflow
         print("ok")
     """
     env = dict(os.environ, JAX_PLATFORMS="cpu",

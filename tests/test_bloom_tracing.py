@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
-from repro.hash import DeviceShardedBloom, HashPlan, ProbeTransport
+from repro.hash import DeviceShardedBloom, HashPlan, distributed
 from repro.hash import hasher as hasher_mod
 from repro.hash.distributed import _bucket_shape
 from repro.parallel.sharding import data_mesh
@@ -135,16 +135,6 @@ def test_counters_are_exact_on_every_in_graph_path(transport):
     assert bloom.stats["exchange_bytes"] == (rows + 128) * k * 8
 
 
-def test_host_transport_stages_nothing():
-    bloom = _bloom("host")
-    bloom.check_and_add_batch(_ragged([4, 2]))
-    assert bloom.stats == {"overflow_fallbacks": 0, "calls": 0, "items": 0,
-                           "real_tokens": 0, "staged_tokens": 0,
-                           "stage_groups": 0,
-                           "stage_reuses": 0, "stage_allocs": 0,
-                           "exchange_slots": 0, "exchange_bytes": 0}
-
-
 def test_admission_call_writes_every_span_nested(tmp_path):
     bloom = _bloom()
     lengths = [6, 1, 9, 4, 3]
@@ -188,10 +178,11 @@ def test_add_and_contains_get_only_the_stage_spans(tmp_path):
     assert sum(e[0] == "bloom.stage" for e in spans) == 2
 
 
-def test_routed_overflow_writes_the_replay_span(tmp_path):
-    tiny = ProbeTransport("routed", capacity_factor=1e-9, capacity_slack=0)
-    bloom = DeviceShardedBloom(n_items=500, mesh=data_mesh(1),
-                               probe_transport=tiny)
+def test_routed_overflow_writes_the_replay_span(tmp_path, monkeypatch):
+    # one probe a routed bucket: every routed launch overflows
+    monkeypatch.setattr(distributed, "_CAPACITY_FACTOR", 1e-9)
+    monkeypatch.setattr(distributed, "_CAPACITY_SLACK", 0)
+    bloom = _bloom()
     warm = _ragged([3, 4, 5])
     bloom.add_batch(warm)
     bloom.check_and_add_batch(warm)  # compile both paths outside the trace

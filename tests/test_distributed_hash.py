@@ -190,21 +190,32 @@ def test_sharded_bloom_probes_in_graph_zero_syncs_one_psum():
 
 
 def test_sharded_bloom_in_graph_matches_host_mod_path():
-    """A/B: the in-graph Barrett reduction and the legacy host `h % m`
-    round-trip produce identical bits and identical decisions."""
+    """A/B: the in-graph Barrett reduction lights exactly the bits of the
+    host `h % m` formula (numpy over the host hash) and decides as the
+    single-device filter, before and after a fused admission."""
     items, other = _ragged(200, 16), _ragged(200, 16)
     dev = DeviceShardedBloom(n_items=200, fp_rate=1e-3,
                              probe_transport="all_gather")
-    host = DeviceShardedBloom(n_items=200, fp_rate=1e-3,
-                              probe_transport="host")
+    bf = BloomFilter(n_items=200, fp_rate=1e-3)
     assert dev.plan.m == dev.m and not dev.plan.is_pow2
+
+    def host_bits(batches):
+        want = np.zeros(dev.m, bool)
+        for b in batches:
+            want[dev.sharded.hasher.hash_batch(b, backend="host")
+                 % np.uint64(dev.m)] = True
+        return want
+
     dev.add_batch(items)
-    host.add_batch(items)
-    np.testing.assert_array_equal(np.asarray(dev.bits), np.asarray(host.bits))
+    bf.add_batch(items)
+    np.testing.assert_array_equal(np.asarray(dev.bits)[:dev.m] != 0,
+                                  host_bits([items]))
     np.testing.assert_array_equal(dev.contains_batch(other),
-                                  host.contains_batch(other))
+                                  bf.contains_batch(other))
     np.testing.assert_array_equal(dev.check_and_add_batch(other),
-                                  host.check_and_add_batch(other))
+                                  ~bf.contains_batch(other))
+    np.testing.assert_array_equal(np.asarray(dev.bits)[:dev.m] != 0,
+                                  host_bits([items, other]))
 
 
 def test_sharded_bloom_dense_input_and_row_bucketing():
@@ -308,23 +319,27 @@ def test_multi_device_bit_identity_and_bloom():
                                       bf.contains_batch(other))
         loads = np.bincount(dsb.owner_shards(items), minlength=8)
         assert (loads > 0).all(), loads  # Lemire routing spreads the load
-        # every transport == legacy host h%m round-trip on a REAL 8-way
-        # mesh: identical bits, identical fused-admission verdicts (dsb is
-        # the default "routed" transport)
-        hostmod = DeviceShardedBloom(n_items=300, fp_rate=1e-3,
-                                     probe_transport="host")
+        # both transports == the host filter on a REAL 8-way mesh:
+        # identical bits, identical fused-admission verdicts (dsb is the
+        # default "routed" transport)
+        def bloom_bits(bf):
+            return np.unpackbits(bf.bits.view(np.uint8),
+                                 bitorder="little")[:bf.m].astype(bool)
         gathered = DeviceShardedBloom(n_items=300, fp_rate=1e-3,
                                       probe_transport="all_gather")
-        hostmod.add_batch(items); gathered.add_batch(items)
-        np.testing.assert_array_equal(np.asarray(dsb.bits),
-                                      np.asarray(hostmod.bits))
+        gathered.add_batch(items)
+        np.testing.assert_array_equal(np.asarray(dsb.bits)[:dsb.m] != 0,
+                                      bloom_bits(bf))
         np.testing.assert_array_equal(np.asarray(dsb.bits),
                                       np.asarray(gathered.bits))
         adm = dsb.check_and_add_batch(other)
-        np.testing.assert_array_equal(adm, hostmod.check_and_add_batch(other))
+        np.testing.assert_array_equal(adm, ~bf.contains_batch(other))
         np.testing.assert_array_equal(adm, gathered.check_and_add_batch(other))
+        bf.add_batch(other)
+        np.testing.assert_array_equal(np.asarray(dsb.bits)[:dsb.m] != 0,
+                                      bloom_bits(bf))
         np.testing.assert_array_equal(np.asarray(dsb.bits),
-                                      np.asarray(hostmod.bits))
+                                      np.asarray(gathered.bits))
         # Barrett digit reduction under shard_map: edge moduli incl. m=1,
         # pow2 and 2^32-1 stay bit-identical to numpy's uint64 %
         from jax.sharding import PartitionSpec as P

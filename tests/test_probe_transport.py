@@ -1,6 +1,7 @@
-"""ProbeTransport API redesign: host/all_gather/routed A/B equivalence,
-the `in_graph_mod=` deprecation shim, bucket-overflow handling, and the
-consumer threading (service backends, ExactDedup, reconcile convergence).
+"""The two probe transports, routed and all_gather: A/B equivalence with
+the single-device `BloomFilter` and the numpy `h mod m` positions, the
+transport check, the bucket-overflow repair, and the consumers' default
+filters (service backends, ExactDedup, reconcile convergence).
 
 D=1 contracts run in-process (the degenerate mesh runs the SAME routed
 all_to_all code path); true multi-device behaviour (D=4) runs in a
@@ -10,18 +11,19 @@ import os
 import subprocess
 import sys
 import textwrap
-import warnings
 
 import numpy as np
 import pytest
 
 from repro.data.dedup import BloomFilter
-from repro.hash import DeviceShardedBloom, ProbeBucketOverflow, ProbeTransport
+from repro.hash import DeviceShardedBloom, distributed
+from repro.hash.distributed import _capacity
+from repro.parallel.sharding import data_mesh
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 RNG = np.random.Generator(np.random.Philox(key=np.uint64(0x9702)))
 
-TRANSPORTS = ["host", "all_gather", "routed"]
+TRANSPORTS = ["all_gather", "routed"]
 
 
 def _ragged(b, max_n):
@@ -29,37 +31,44 @@ def _ragged(b, max_n):
                          dtype=np.uint64).astype(np.uint32) for _ in range(b)]
 
 
+def _bloom_bits(bf) -> np.ndarray:
+    """The host filter's m bits, one bool each."""
+    return np.unpackbits(bf.bits.view(np.uint8),
+                         bitorder="little")[:bf.m].astype(bool)
+
+
+@pytest.fixture
+def tiny_capacity(monkeypatch):
+    """One probe a routed bucket: every routed launch overflows."""
+    monkeypatch.setattr(distributed, "_CAPACITY_FACTOR", 1e-9)
+    monkeypatch.setattr(distributed, "_CAPACITY_SLACK", 0)
+
+
 # ---------------------------------------------------------------------------
-# the spec object
+# the transport check and the bucket capacity
 # ---------------------------------------------------------------------------
 
-def test_probe_transport_validation():
-    assert ProbeTransport.of("routed").kind == "routed"
-    pt = ProbeTransport("all_gather", capacity_factor=2.0)
-    assert ProbeTransport.of(pt) is pt
-    with pytest.raises(ValueError, match="kind"):
-        ProbeTransport("carrier_pigeon")
-    with pytest.raises(ValueError, match="on_overflow"):
-        ProbeTransport("routed", on_overflow="shrug")
-    with pytest.raises(ValueError, match="capacity_factor"):
-        ProbeTransport("routed", capacity_factor=0.0)
-    with pytest.raises(ValueError, match="capacity_slack"):
-        ProbeTransport("routed", capacity_slack=-1)
-    with pytest.raises(TypeError):
-        ProbeTransport.of(7)
+@pytest.mark.parametrize("bad", ["host", "carrier_pigeon", 7])
+def test_probe_transport_validation(bad):
+    with pytest.raises(ValueError, match="'routed' nor 'all_gather'"):
+        DeviceShardedBloom(n_items=64, probe_transport=bad)
+    with pytest.raises(TypeError):  # the boolean it replaced is gone
+        DeviceShardedBloom(n_items=64, in_graph_mod=True)
 
 
-def test_probe_transport_capacity():
-    pt = ProbeTransport()
+def test_probe_transport_capacity(monkeypatch):
     # never exceeds the probe count, never below 1
-    assert pt.capacity(100, 1) == 100
-    assert ProbeTransport("routed", capacity_factor=1e-9,
-                          capacity_slack=0).capacity(100, 4) == 1
+    assert _capacity(100, 1) == 100
     # headroom: cap * D covers the probes with the factor to spare
-    cap = pt.capacity(4096, 4)
+    cap = _capacity(4096, 4)
     assert 4096 * 1.25 / 4 <= cap <= 4096
-    # default factor >= 1 makes D=1 structurally overflow-free
-    assert pt.capacity(7, 1) == 7
+    assert cap == 1280 + 16
+    # the factor >= 1 makes D=1 structurally overflow-free
+    assert _capacity(7, 1) == 7
+    # the test seam: shrunk constants leave one slot a bucket
+    monkeypatch.setattr(distributed, "_CAPACITY_FACTOR", 1e-9)
+    monkeypatch.setattr(distributed, "_CAPACITY_SLACK", 0)
+    assert _capacity(100, 4) == 1
 
 
 # ---------------------------------------------------------------------------
@@ -81,16 +90,31 @@ def test_transport_matches_bloom_filter(transport):
                                   ~bf.contains_batch(other))
 
 
+@pytest.mark.parametrize("transport", TRANSPORTS)
+def test_in_graph_bits_are_the_host_h_mod_m_positions(transport):
+    """The host formula as a reference: the lit bits are exactly the numpy
+    set of `h mod m` over the filter's own 64-bit hashes of the items."""
+    items = _ragged(150, 12)
+    dsb = DeviceShardedBloom(n_items=200, mesh=data_mesh(1),
+                             probe_transport=transport)
+    dsb.add_batch(items)
+    h = dsb.sharded.hasher.hash_batch(items, backend="host")
+    want = np.unique(h % np.uint64(dsb.m))
+    np.testing.assert_array_equal(np.flatnonzero(np.asarray(dsb.bits)), want)
+
+
 def test_transports_produce_identical_bit_state():
     items, more = _ragged(150, 12), _ragged(70, 12)
-    filters = {t: DeviceShardedBloom(n_items=200, probe_transport=t)
-               for t in TRANSPORTS}
-    for f in filters.values():
+    bf = BloomFilter(n_items=200)
+    bf.add_batch(items)
+    bf.add_batch(more)
+    for t in TRANSPORTS:
+        f = DeviceShardedBloom(n_items=200, probe_transport=t)
         f.add_batch(items)
         f.check_and_add_batch(more)
-    ref = np.asarray(filters["host"].bits)
-    for t in ("all_gather", "routed"):
-        np.testing.assert_array_equal(np.asarray(filters[t].bits), ref, t)
+        bits = np.asarray(f.bits)
+        np.testing.assert_array_equal(bits[:f.m] != 0, _bloom_bits(bf), t)
+        assert not bits[f.m:].any(), t
 
 
 def test_routed_sentinel_rows_owned_by_no_device():
@@ -113,99 +137,41 @@ def test_routed_sentinel_rows_owned_by_no_device():
 
 
 # ---------------------------------------------------------------------------
-# the in_graph_mod= deprecation shim
-# ---------------------------------------------------------------------------
-
-def _one_warning(fn):
-    """Run fn capturing warnings; assert exactly one DeprecationWarning."""
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        out = fn()
-    dep = [w for w in rec if issubclass(w.category, DeprecationWarning)
-           and "repro.hash" in str(w.message)]
-    assert len(dep) == 1, [str(w.message) for w in rec]
-    return out
-
-
-@pytest.mark.parametrize("legacy,kind", [(True, "all_gather"),
-                                         (False, "host")])
-def test_in_graph_mod_shim_bit_identity(legacy, kind):
-    """One DeprecationWarning, and the shim maps onto exactly the transport
-    the boolean used to select -- pinned by identical bits and verdicts."""
-    items, other = _ragged(100, 10), _ragged(40, 10)
-    old = _one_warning(lambda: DeviceShardedBloom(
-        n_items=100, in_graph_mod=legacy))
-    assert old.transport.kind == kind
-    assert old.in_graph_mod is legacy  # read-only property keeps answering
-    new = DeviceShardedBloom(n_items=100, probe_transport=kind)
-    old.add_batch(items)
-    new.add_batch(items)
-    np.testing.assert_array_equal(np.asarray(old.bits), np.asarray(new.bits))
-    np.testing.assert_array_equal(old.check_and_add_batch(other),
-                                  new.check_and_add_batch(other))
-
-
-def test_probe_transport_kwarg_warns_nothing():
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        DeviceShardedBloom(n_items=64, probe_transport="routed")
-        DeviceShardedBloom(n_items=64,
-                           probe_transport=ProbeTransport("all_gather"))
-    assert not [w for w in rec if issubclass(w.category, DeprecationWarning)]
-
-
-# ---------------------------------------------------------------------------
 # overflow chaos (D=1; the D=4 twin runs in the subprocess test below)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.chaos
-def test_overflow_fallback_is_bit_identical():
-    """A pathologically tiny capacity forces bucket overflow on every call;
-    the fallback path must still produce BloomFilter-identical verdicts and
-    bits, and count its fallbacks."""
+@pytest.mark.parametrize("op", ["add", "contains", "check_and_add"])
+def test_overflow_fallback_is_bit_identical(op, tiny_capacity):
+    """Every routed launch overflows its one-slot buckets; each operation's
+    replay through all_gather must still give BloomFilter-identical
+    verdicts and bits, and count one fallback a launch."""
     items, other = _ragged(120, 10), _ragged(50, 10)
     bf = BloomFilter(n_items=150)
-    tiny = ProbeTransport("routed", capacity_factor=1e-9, capacity_slack=0)
-    dsb = DeviceShardedBloom(n_items=150, probe_transport=tiny)
+    dsb = DeviceShardedBloom(n_items=150)
     bf.add_batch(items)
-    dsb.add_batch(items)
-    np.testing.assert_array_equal(dsb.contains_batch(other),
-                                  bf.contains_batch(other))
-    np.testing.assert_array_equal(dsb.check_and_add_batch(other),
-                                  ~bf.contains_batch(other))
-    assert dsb.stats["overflow_fallbacks"] >= 2
-
-
-@pytest.mark.chaos
-def test_overflow_error_policy_raises_typed_error():
-    tiny = ProbeTransport("routed", capacity_factor=1e-9, capacity_slack=0,
-                          on_overflow="error")
-    dsb = DeviceShardedBloom(n_items=150, probe_transport=tiny)
-    items = _ragged(60, 10)
-    with pytest.raises(ProbeBucketOverflow, match="capacity"):
-        dsb.add_batch(items)   # deferred flag settles inside the batch loop
-        dsb.contains_batch(items)
-    # the repair ran before the raise: state is still BloomFilter-identical
-    bf = BloomFilter(n_items=150)
-    bf.add_batch(items)
-    probe = _ragged(30, 10)
-    relaxed = DeviceShardedBloom(n_items=150, probe_transport="routed")
-    relaxed._bits = dsb._bits
-    np.testing.assert_array_equal(relaxed.contains_batch(probe),
-                                  bf.contains_batch(probe))
+    dsb.add_batch(items)  # its flag settles at the next call or bits read
+    if op == "contains":
+        np.testing.assert_array_equal(dsb.contains_batch(other),
+                                      bf.contains_batch(other))
+    elif op == "check_and_add":
+        np.testing.assert_array_equal(dsb.check_and_add_batch(other),
+                                      ~bf.contains_batch(other))
+        bf.add_batch(other)
+    bits = np.asarray(dsb.bits)
+    np.testing.assert_array_equal(bits[:dsb.m] != 0, _bloom_bits(bf))
+    assert dsb.stats["overflow_fallbacks"] == (1 if op == "add" else 2)
 
 
 # ---------------------------------------------------------------------------
-# consumer threading (D=1 in-process)
+# consumers build the default routed filter (D=1 in-process)
 # ---------------------------------------------------------------------------
 
 def test_service_over_device_sharded_backends():
     from repro.hash.service import AdmissionService
-    from repro.parallel.sharding import data_mesh
 
     items = _ragged(64, 8)
-    svc = AdmissionService.over_bloom_shards(
-        2, 1 << 12, mesh=data_mesh(), probe_transport="routed")
+    svc = AdmissionService.over_bloom_shards(2, 1 << 12, mesh=data_mesh())
     host_svc = AdmissionService.over_bloom_shards(2, 1 << 12)
     first = svc.admit_batch(items)
     np.testing.assert_array_equal(first, host_svc.admit_batch(items))
@@ -213,21 +179,19 @@ def test_service_over_device_sharded_backends():
     assert not svc.admit_batch(items).any()
     for b in svc.transport.backends:
         assert isinstance(b.filt, DeviceShardedBloom)
-        assert b.filt.transport.kind == "routed"
+        assert b.filt.transport == "routed"
 
 
 def test_exact_dedup_approx_mode():
     from repro.data.dedup import ExactDedup
-    from repro.parallel.sharding import data_mesh
 
     docs = _ragged(80, 10)
     exact = ExactDedup()
-    approx = ExactDedup(mesh=data_mesh(), approx_items=4096,
-                        probe_transport="routed")
+    approx = ExactDedup(mesh=data_mesh(), approx_items=4096)
     np.testing.assert_array_equal(approx.add_documents(docs),
                                   exact.add_documents(docs))
     assert not approx.add_documents(docs).any()
-    assert approx._bloom.transport.kind == "routed"
+    assert approx._bloom.transport == "routed"
 
 
 # ---------------------------------------------------------------------------
@@ -243,9 +207,9 @@ def test_multi_device_transport_equivalence_and_reconcile():
         import jax
         from repro.data.dedup import BloomFilter
         from repro.hash import (DeviceShardedBloom, FaultEvent, FaultPlan,
-                                FaultyTransport, InProcessTransport,
-                                ProbeBucketOverflow, ProbeTransport,
-                                VirtualClock, bloom_shard_backends)
+                                FaultyTransport, FilterShardBackend,
+                                InProcessTransport, VirtualClock,
+                                bloom_shard_backends, distributed)
         from repro.hash.service import AdmissionService
         from repro.parallel.sharding import data_mesh
 
@@ -260,8 +224,10 @@ def test_multi_device_transport_equivalence_and_reconcile():
         bf = BloomFilter(n_items=200, fp_rate=1e-3)
         bf.add_batch(items)
         ref_mask = bf.contains_batch(other)
-        bits = {}
-        for kind in ("host", "all_gather", "routed"):
+        bf.add_batch(other)
+        ref_bits = np.unpackbits(bf.bits.view(np.uint8),
+                                 bitorder="little")[:bf.m].astype(bool)
+        for kind in ("all_gather", "routed"):
             f = DeviceShardedBloom(n_items=200, fp_rate=1e-3,
                                    probe_transport=kind)
             assert f.n_shards == 4
@@ -270,30 +236,22 @@ def test_multi_device_transport_equivalence_and_reconcile():
             np.testing.assert_array_equal(f.contains_batch(other), ref_mask)
             np.testing.assert_array_equal(f.check_and_add_batch(other),
                                           ~ref_mask)
-            bits[kind] = np.asarray(f.bits)
-        np.testing.assert_array_equal(bits["host"], bits["all_gather"])
-        np.testing.assert_array_equal(bits["host"], bits["routed"])
+            bits = np.asarray(f.bits)
+            np.testing.assert_array_equal(bits[:f.m] != 0, ref_bits)
+            assert not bits[f.m:].any()
 
         # overflow chaos on a REAL 4-way exchange: fallback bit-identity
-        tiny = ProbeTransport("routed", capacity_factor=0.02,
-                              capacity_slack=0)
-        f = DeviceShardedBloom(n_items=200, fp_rate=1e-3,
-                               probe_transport=tiny)
+        saved = distributed._CAPACITY_FACTOR, distributed._CAPACITY_SLACK
+        distributed._CAPACITY_FACTOR, distributed._CAPACITY_SLACK = 0.02, 0
+        f = DeviceShardedBloom(n_items=200, fp_rate=1e-3)
         f.add_batch(items)
         np.testing.assert_array_equal(f.contains_batch(other), ref_mask)
         np.testing.assert_array_equal(f.check_and_add_batch(other),
                                       ~ref_mask)
         assert f.stats["overflow_fallbacks"] >= 1, f.stats
-        np.testing.assert_array_equal(np.asarray(f.bits), bits["host"])
-        hard = ProbeTransport("routed", capacity_factor=0.02,
-                              capacity_slack=0, on_overflow="error")
-        f = DeviceShardedBloom(n_items=200, fp_rate=1e-3,
-                               probe_transport=hard)
-        try:
-            f.add_batch(items); f.contains_batch(items)
-            raise SystemExit("expected ProbeBucketOverflow")
-        except ProbeBucketOverflow:
-            pass
+        np.testing.assert_array_equal(np.asarray(f.bits)[:f.m] != 0,
+                                      ref_bits)
+        distributed._CAPACITY_FACTOR, distributed._CAPACITY_SLACK = saved
 
         # admission service under faults: routed and all_gather backends
         # see identical verdicts, and after reconcile_all the sharded
@@ -305,9 +263,12 @@ def test_multi_device_transport_equivalence_and_reconcile():
             plan = FaultPlan(11, events=[
                 FaultEvent("crash", shard=1, at=0, until=2)],
                 p_timeout=0.1)
-            backends = bloom_shard_backends(
-                2, 1 << 12, mesh=data_mesh(),
-                probe_transport=kind)
+            if kind == "routed":
+                backends = bloom_shard_backends(2, 1 << 12, mesh=data_mesh())
+            else:
+                backends = [FilterShardBackend(DeviceShardedBloom(
+                    n_items=1 << 11, mesh=data_mesh(),
+                    probe_transport=kind)) for _ in range(2)]
             svc = AdmissionService(
                 FaultyTransport(InProcessTransport(backends), plan, clock),
                 clock=clock, policy="fail_open")

@@ -139,14 +139,14 @@ def test_grouped_admission_compiles_on_four_chips(topo):
     cut from one token arena, one kernel each, then the (owner, local)
     exchange, the test and the scatter. The 1.80 GB shard of bits is only
     described."""
-    from repro.hash import ProbeTransport
     from repro.hash.distributed import TokenArena, _admission_surfaces
 
     mesh = make_mesh((4,), ("data",), devices=topo.devices)
     m, k, D = 7_188_793_783, 9, 4
     groups = ((3840, 2048), (256, 32768))
-    surfaces = _admission_surfaces(mesh, "data", m, ModPlan.for_modulus(m),
-                                   ProbeTransport())
+    surfaces = _admission_surfaces(mesh, "data", m, ModPlan.for_modulus(m))
+    assert sorted(surfaces) == ["_add_dev", "_add_rt", "_admit_dev",
+                                "_admit_rt", "_contains_dev", "_contains_rt"]
     h = Hasher.from_spec(HashSpec(n_hashes=k, out_bits=64,
                                   variable_length=True), max_len=32768,
                          plan=HashPlan(backend="pallas"))

@@ -56,7 +56,7 @@ def test_four_devices_hold_the_bloom_filter_bits():
         import jax.numpy as jnp
         from repro.data.dedup import BloomFilter
         from repro.hash import (DeviceShardedBloom, Hasher, HashSpec,
-                                ProbeTransport)
+                                distributed)
         from repro.hash.distributed import probe_owner_local
         from repro.parallel.sharding import data_mesh
 
@@ -72,9 +72,13 @@ def test_four_devices_hold_the_bloom_filter_bits():
                                  bitorder="little")[:bf.m].astype(bool)
 
         items, other = ragged(300, 18), ragged(300, 18)
-        tiny = ProbeTransport("routed", capacity_factor=0.02,
-                              capacity_slack=0)
-        for transport in ("host", "all_gather", "routed", tiny):
+        for transport, tiny in (("all_gather", False), ("routed", False),
+                                ("routed", True)):
+            if tiny:
+                # buckets far below the expected load: every routed call
+                # overflows and replays through all_gather
+                distributed._CAPACITY_FACTOR = 0.02
+                distributed._CAPACITY_SLACK = 0
             bf = BloomFilter(n_items=300, fp_rate=1e-3)
             f = DeviceShardedBloom(n_items=300, fp_rate=1e-3,
                                    probe_transport=transport)
@@ -91,7 +95,7 @@ def test_four_devices_hold_the_bloom_filter_bits():
             np.testing.assert_array_equal(bits[:f.m] != 0, bloom_bits(bf))
             assert not bits[f.m:].any()
             assert len(f.bits.addressable_shards) == 4
-            if transport is tiny:
+            if tiny:
                 assert f.stats["overflow_fallbacks"] >= 2, f.stats
 
         # the wide residue through the sharded kernel surface, split by
